@@ -37,10 +37,10 @@ unit of dispatch, and make the pool the only KV substrate decode touches:
     ``kv_pools`` (an ordered mapping of NAMED pools, one per cache stack --
     the MoE serving layout is ``{"dense": (chunk, 2, fd*kv_heads, hd),
     "moe": (chunk, 2, nm*kv_heads, hd)}``) the engine owns a
-    device-resident ``(n_blocks, row)`` payload pool alongside the
-    ``(wts, rts)`` metadata; each row is ``chunk`` lane-padded TOKEN rows,
-    so a single token is one aligned row of the ``(n_blocks*chunk,
-    token_row)`` flat view (``kv_rows_view``).  With multiple pools the
+    device-resident ``(n_blocks*chunk, token_row)`` payload pool alongside
+    the ``(wts, rts)`` metadata: each block is ``chunk`` consecutive
+    lane-padded TOKEN rows, so a single token is one row of the pool
+    (``kv_rows_view``).  With multiple pools the
     token row **interleaves** every stack's segment (each lane-padded, at a
     static ``pool_offset``), so ONE block id leases every stack's payload
     and every transition -- lease, write, eviction, relocation, rebase,
@@ -217,11 +217,11 @@ class LeaseEngine:
                                       self.alloc_reserve - 1, -1))
         # O(1) membership for the double-free / never-allocated guards
         self._free_set = set(self._free_pages)
-        # paged KV payload pool(s): one row per block = ``chunk`` lane-padded
-        # TOKEN rows back to back, so a single decoded token's KV is one
-        # aligned row in the (n_blocks*chunk, token_row) flat view (the
-        # decode kernels' substrate) and a whole block is ``chunk``
-        # consecutive rows (the gather kernel's).  With MULTIPLE named
+        # paged KV payload pool(s): ``chunk`` lane-padded TOKEN rows per
+        # block, back to back, so a single decoded token's KV is one row
+        # of the (n_blocks*chunk, token_row) pool (the decode kernels'
+        # substrate) and a whole block is ``chunk`` consecutive rows (the
+        # gather kernel's).  With MULTIPLE named
         # pools (one per cache stack) each token row interleaves every
         # stack's lane-padded segment at a static column offset -- one
         # block id owns every stack's payload, one free list pages them,
@@ -258,13 +258,14 @@ class LeaseEngine:
                                          "token_row": row}
                 off += row
             self.kv_token_row = off
-            self._kv_row = self.kv_chunk * self.kv_token_row
+            # stored as token rows, the layout every kernel reads: a
+            # reshape to (n_blocks, chunk * row) would be a relayout copy
+            # of the whole pool on the chip
+            rows = (self.n_blocks * self.kv_chunk, self.kv_token_row)
             if backend == "pallas":
-                self._kv_pool = jnp.zeros((self.n_blocks, self._kv_row),
-                                          kv_dtype)
+                self._kv_pool = jnp.zeros(rows, kv_dtype)
             else:
-                self._kv_pool = np.zeros((self.n_blocks, self._kv_row),
-                                         np.dtype(kv_dtype))
+                self._kv_pool = np.zeros(rows, np.dtype(kv_dtype))
             self._kv_valid = np.zeros(self.n_blocks, bool)
             self.stats.kv_pool_tokens = {n: 0 for n in self.kv_pools}
         # runtime lease sanitizer (repro.analysis.sanitize): host-side
@@ -410,19 +411,25 @@ class LeaseEngine:
         flat = xp.concatenate(
             [self._pack_rows(blocks[name], idx.size, xp, name)
              for name in self.kv_pools], axis=-1
-        ).reshape(idx.size, self._kv_row)
+        ).reshape(idx.size * self.kv_chunk, self.kv_token_row)
+        rows_idx = self._token_rows(idx)
         if self.backend == "pallas":
             with warnings.catch_warnings():
                 # CPU XLA can't honor the donation; the TPU path does
                 warnings.filterwarnings("ignore", message=".*donated.*")
                 self._kv_pool = _scatter_rows(self._kv_pool,
-                                              jnp.asarray(idx), flat)
+                                              jnp.asarray(rows_idx), flat)
         else:
-            self._kv_pool[idx] = flat.astype(self._kv_pool.dtype)
+            self._kv_pool[rows_idx] = flat.astype(self._kv_pool.dtype)
         self._kv_valid[idx] = True
         self.stats.kv_blocks_written += int(idx.size)
         if self._san is not None:
             self._san.after(self, "write_kv", blocks=idx)
+
+    def _token_rows(self, idx: np.ndarray) -> np.ndarray:
+        """Pool token-row ids of blocks ``idx``, ``chunk`` per block."""
+        return (idx[:, None] * self.kv_chunk
+                + np.arange(self.kv_chunk)).reshape(-1)
 
     def _rows_to_blocks(self, rows, n: int, pool: str):
         """(n, chunk, row_p) padded rows -> (n, *pool_shape) payloads."""
@@ -448,31 +455,23 @@ class LeaseEngine:
                 return np.zeros((0,) + shape, dtype)
             return {n_: np.zeros((0,) + s, dtype)
                     for n_, s in self.kv_pools.items()}
+        # token-granular gather over the whole token row, or over one
+        # stack's column window
+        lo, width = 0, self.kv_token_row
         if pool is not None:
             meta = self._pool_meta[pool]
-            # token-granular gather over the stack's column window
-            rows_idx = (idx[:, None] * self.kv_chunk
-                        + np.arange(self.kv_chunk)).reshape(-1)
-            if self.backend == "pallas":
-                rows = lease_ops.gather_blocks(
-                    self.kv_rows_view(), jnp.asarray(rows_idx, jnp.int32),
-                    col_lo=meta["offset"], width=meta["token_row"],
-                    interpret=self.interpret)
-            else:
-                rows = self._kv_pool.reshape(-1, self.kv_token_row)[
-                    rows_idx,
-                    meta["offset"]:meta["offset"] + meta["token_row"]]
-            self.stats.kv_blocks_read += int(idx.size)
-            rows = rows.reshape(idx.size, self.kv_chunk, meta["token_row"])
-            return self._rows_to_blocks(rows, idx.size, pool)
+            lo, width = meta["offset"], meta["token_row"]
+        rows_idx = self._token_rows(idx)
         if self.backend == "pallas":
             rows = lease_ops.gather_blocks(
-                self._kv_pool, jnp.asarray(idx, jnp.int32),
-                interpret=self.interpret)
+                self._kv_pool, jnp.asarray(rows_idx, jnp.int32),
+                col_lo=lo, width=width, interpret=self.interpret)
         else:
-            rows = self._kv_pool[idx]
+            rows = self._kv_pool[rows_idx, lo:lo + width]
         self.stats.kv_blocks_read += int(idx.size)
-        rows = rows.reshape(idx.size, self.kv_chunk, self.kv_token_row)
+        rows = rows.reshape(idx.size, self.kv_chunk, width)
+        if pool is not None:
+            return self._rows_to_blocks(rows, idx.size, pool)
         out = {}
         for name, meta in self._pool_meta.items():
             seg = rows[:, :, meta["offset"]:meta["offset"]
@@ -554,19 +553,18 @@ class LeaseEngine:
     def kv_rows_view(self):
         """The pool as (n_blocks*chunk, token_row) device rows -- the
         substrate the paged decode step reads and appends against."""
-        pool = self._kv_pool if self.backend == "pallas" \
-            else jnp.asarray(self._kv_pool)
-        return pool.reshape(self.n_blocks * self.kv_chunk, self.kv_token_row)
+        if self.backend == "pallas":
+            return self._kv_pool
+        return jnp.asarray(self._kv_pool)
 
     def set_kv_rows(self, rows, tokens_appended: int = 0) -> None:
         """Write back the (possibly donated) rows view after a jitted
         decode step appended token KV in place.  An appended row spans the
         whole interleaved token row, so it feeds every stack's counter."""
-        pool = rows.reshape(self.n_blocks, self._kv_row)
         if self.backend == "pallas":
-            self._kv_pool = pool
+            self._kv_pool = rows
         else:
-            self._kv_pool = np.asarray(pool)
+            self._kv_pool = np.asarray(rows)
         self.stats.kv_tokens_appended += int(tokens_appended)
         for name in self.kv_pools:
             self.stats.kv_pool_tokens[name] = (
@@ -604,11 +602,10 @@ class LeaseEngine:
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore", message=".*donat.*")
                     self._kv_pool = lease_ops.append_rows(
-                        self.kv_rows_view(),
+                        self._kv_pool,
                         jnp.asarray(rows_idx, jnp.int32), jnp.asarray(rows),
                         col_lo=meta["offset"], width=meta["token_row"],
-                        interpret=self.interpret,
-                    ).reshape(self.n_blocks, self._kv_row)
+                        interpret=self.interpret)
             else:
                 # write the stack's WHOLE lane-padded window (zeros in the
                 # padding), exactly like the kernel's LANES-block DMA --
@@ -618,10 +615,9 @@ class LeaseEngine:
                                 self._kv_pool.dtype)
                 flat[:, :meta["token_elems"]] = rows.astype(
                     self._kv_pool.dtype)
-                view = self._kv_pool.reshape(-1, self.kv_token_row)
-                view[rows_idx,
-                     meta["offset"]:meta["offset"] + meta["token_row"]] \
-                    = flat
+                self._kv_pool[
+                    rows_idx,
+                    meta["offset"]:meta["offset"] + meta["token_row"]] = flat
             self.stats.kv_tokens_appended += int(rows_idx.size)
             self.stats.kv_pool_tokens[pool] = (
                 self.stats.kv_pool_tokens.get(pool, 0) + int(rows_idx.size))
@@ -638,15 +634,13 @@ class LeaseEngine:
             with warnings.catch_warnings():
                 warnings.filterwarnings("ignore", message=".*donat.*")
                 self._kv_pool = lease_ops.append_rows(
-                    self.kv_rows_view(), jnp.asarray(rows_idx, jnp.int32),
-                    jnp.asarray(rows), interpret=self.interpret,
-                ).reshape(self.n_blocks, self._kv_row)
+                    self._kv_pool, jnp.asarray(rows_idx, jnp.int32),
+                    jnp.asarray(rows), interpret=self.interpret)
         else:
             flat = np.zeros((rows_idx.size, self.kv_token_row),
                             self._kv_pool.dtype)
             flat[:, :rows.shape[1]] = rows
-            view = self._kv_pool.reshape(-1, self.kv_token_row)
-            view[rows_idx] = flat
+            self._kv_pool[rows_idx] = flat
         blocks = np.unique(rows_idx // self.kv_chunk)
         self._kv_valid[blocks] = True
         self.stats.kv_tokens_appended += int(rows_idx.size)

@@ -1,10 +1,10 @@
 """Activation sharding annotations bound to the ambient mesh.
 
 Model code calls :func:`batch_activations` on residual streams and
-:func:`replicate` on tiny decode activations.  Under a mesh context (the
-dry-run's ``with mesh:`` / ``set_mesh``) these lower to
-``with_sharding_constraint``; outside any mesh context they are exact
-no-ops, so the same model code runs unannotated on a single host.
+:func:`replicate` on tiny decode activations.  Under a mesh set with
+``jax.set_mesh`` (the dry-run's context) these lower to
+``with_sharding_constraint``; outside any mesh they are exact no-ops, so
+the same model code runs unannotated on a single host.
 """
 from __future__ import annotations
 
@@ -15,28 +15,9 @@ from .sharding import activation_spec
 
 
 def ambient_mesh():
-    """The mesh currently in scope, or None.
-
-    Prefers the new-style abstract mesh (``jax.sharding.set_mesh`` /
-    ``use_mesh``); falls back to the legacy ``with mesh:`` context
-    (``thread_resources.env.physical_mesh``) on older jax.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            m = get_abstract()
-            if m is not None and not m.empty:
-                return m
-        except Exception:
-            pass
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def batch_activations(x):

@@ -99,43 +99,44 @@ def decode_attention_grouped(q, k, v, kv_len, *, scale: float,
 
 def _paged_decode_kernel(scalars_ref, q_ref, cur_k_ref, cur_v_ref, pool_ref,
                          o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                         chunk: int, k_off: int, v_off: int, hk: int,
-                         dh: int, num_pages: int):
+                         chunk: int, k_off: int, v_off: int, hkd: int,
+                         num_pages: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
     kv_len = scalars_ref[b]                           # this request's tokens
 
-    q = q_ref[0].astype(jnp.float32)                  # (hk, g, dh)
+    # block-diagonal query: row (h, g) holds q[h, g] in head h's dh columns
+    # and zeros elsewhere, so one 2-D matmul against a page's (chunk,
+    # hk*dh) K slice scores every head against its own KV head only
+    q = q_ref[0].astype(jnp.float32)                  # (hk*g, hk*dh)
 
     @pl.when(j == 0)
     def _init():
         # fold the CURRENT token (always attended, position == kv_len)
         # into fresh accumulators before any pool page streams in
-        ck = cur_k_ref[0].astype(jnp.float32)         # (hk, dh)
+        ck = cur_k_ref[0].astype(jnp.float32)         # (1, hk*dh)
         cv = cur_v_ref[0].astype(jnp.float32)
-        s0 = jnp.sum(q * ck[:, None, :], axis=-1, keepdims=True) * scale
-        m_scr[...] = s0                               # (hk, g, 1)
+        s0 = jnp.sum(q * ck, axis=1, keepdims=True) * scale
+        m_scr[...] = s0                               # (hk*g, 1)
         l_scr[...] = jnp.ones_like(s0)
-        acc_scr[...] = jnp.broadcast_to(cv[:, None, :], acc_scr.shape)
+        acc_scr[...] = jnp.broadcast_to(cv, acc_scr.shape)
 
-    rows = pool_ref[...]                              # (chunk, token_row)
-    k = rows[:, k_off:k_off + hk * dh].reshape(chunk, hk, dh)
-    v = rows[:, v_off:v_off + hk * dh].reshape(chunk, hk, dh)
-    k = k.astype(jnp.float32).transpose(1, 0, 2)      # (hk, chunk, dh)
-    v = v.astype(jnp.float32).transpose(1, 0, 2)
-    # (hk, g, chunk): contract dh, batch over the kv heads
-    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,)))) * scale
-    kpos = j * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(kpos < kv_len, s, NEG_INF)
+    # lane-aligned 2-D slices of the page's token rows: (chunk, hk*dh)
+    k = pool_ref[:, k_off:k_off + hkd].astype(jnp.float32)
+    v = pool_ref[:, v_off:v_off + hkd].astype(jnp.float32)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+    kpos = j * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(kpos < kv_len, s, NEG_INF)          # (hk*g, chunk)
 
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.where(m_new <= NEG_INF, 0.0, jnp.exp(s - m_new))
     alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_scr[...] = m_new
+    # (hk*g, hk*dh): row (h, g) is meaningful only in head h's columns
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))))
+        p, v, (((1,), (0,)), ((), ())))
 
     @pl.when(j == num_pages - 1)
     def _finish():
@@ -153,36 +154,47 @@ def paged_decode_attention_grouped(q, cur_k, cur_v, pool_rows, page_rows,
     must be clamped valid); lengths: (B,) int32 tokens already in pages.
 
     Attends over [pool tokens 0..lengths[b]) ; current token] per request.
+    The kernel works on 2-D tiles only: the query enters block-diagonal
+    (B, Hkv*G, Hkv*Dh), K and V are the page's (chunk, Hkv*Dh) column
+    slices, and each head's output is read back off the diagonal here.
     """
     b, hk, g, dh = q.shape
+    hkd = hk * dh
     num_pages = page_rows.shape[1]
     token_row = pool_rows.shape[1]
+    own_head = jnp.eye(hk, dtype=bool)[:, None, :, None]      # (hk,1,hk,1)
+    q_bd = jnp.where(own_head, q[:, :, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(b, hk * g, hkd)
     scalars = jnp.concatenate([
         jnp.asarray(lengths, jnp.int32).reshape(-1),
         jnp.asarray(page_rows, jnp.int32).reshape(-1)])
     grid = (b, num_pages)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, chunk=chunk,
-                          k_off=k_off, v_off=v_off, hk=hk, dh=dh,
+                          k_off=k_off, v_off=v_off, hkd=hkd,
                           num_pages=num_pages),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, hk, g, dh), lambda bb, j, _s: (bb, 0, 0, 0)),
-                pl.BlockSpec((1, hk, dh), lambda bb, j, _s: (bb, 0, 0)),
-                pl.BlockSpec((1, hk, dh), lambda bb, j, _s: (bb, 0, 0)),
+                pl.BlockSpec((1, hk * g, hkd), lambda bb, j, _s: (bb, 0, 0)),
+                pl.BlockSpec((1, 1, hkd), lambda bb, j, _s: (bb, 0, 0)),
+                pl.BlockSpec((1, 1, hkd), lambda bb, j, _s: (bb, 0, 0)),
                 # the page table drives the pool DMA: page j of request bb
                 pl.BlockSpec((chunk, token_row),
                              lambda bb, j, s: (s[b + bb * num_pages + j], 0)),
             ],
-            out_specs=pl.BlockSpec((1, hk, g, dh),
-                                   lambda bb, j, _s: (bb, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, hk * g, hkd),
+                                   lambda bb, j, _s: (bb, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((hk, g, 1), jnp.float32),
-                pltpu.VMEM((hk, g, 1), jnp.float32),
-                pltpu.VMEM((hk, g, dh), jnp.float32),
+                pltpu.VMEM((hk * g, 1), jnp.float32),
+                pltpu.VMEM((hk * g, 1), jnp.float32),
+                pltpu.VMEM((hk * g, hkd), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk * g, hkd), q.dtype),
         interpret=interpret,
-    )(scalars, q, cur_k, cur_v, pool_rows)
+    )(scalars, q_bd, cur_k.reshape(b, 1, hkd), cur_v.reshape(b, 1, hkd),
+      pool_rows)
+    # row (h, g) of head h's column block: the diagonal of (hk, hk)
+    out = jnp.diagonal(out.reshape(b, hk, g, hk, dh), axis1=1, axis2=3)
+    return out.transpose(0, 3, 1, 2)                  # (b, hk, g, dh)

@@ -31,14 +31,15 @@ evaluated against the *pre-call* table, which is the wave semantics: every
 requester of the wave observes the same table snapshot.
 
 ``_gather_kernel`` is the paged-KV materialization path: scalar-prefetched
-block ids drive the input index map directly (the classic paged-attention
-gather), so leased KV chunks stream from the pool into a replica's cache
-without a host round-trip.  Both the gather and the scatter
-(``scatter_rows``) take a **column window** -- a LANES-aligned
-``col_lo``/``width`` pair that becomes a second grid dimension in the
-index maps -- so a multi-pool engine (one named KV pool per cache stack,
-interleaved inside each token row) can stream or append a single stack's
-segment without touching its neighbors' bits.
+row ids address the pool in HBM, so leased KV chunks stream from the pool
+into a replica's cache without a host round-trip.  A DMA moves whole
+memory tiles of rows (16 rows of bf16), so the gather and the scatter
+(``scatter_rows``) reach a single token row through the aligned tile that
+holds it.  Both take a **column window** -- a LANES-aligned
+``col_lo``/``width`` pair that is the DMA's column slice -- so a
+multi-pool engine (one named KV pool per cache stack, interleaved inside
+each token row) can stream or append a single stack's segment without
+touching its neighbors' bits.
 
 pts/lease (and ts for the advance pass) arrive via scalar prefetch so a
 serving engine can stream tables through the same compiled kernels; a
@@ -49,6 +50,9 @@ Unselected blocks pass through untouched, which is also how ragged tables
 are handled: the padding lanes simply carry mask == 0.
 """
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -285,30 +289,65 @@ def lease_table_many(wts, rts, req_wts, masks, pts_vec, lease, *,
     )(scalars, *tables)
 
 
-def _gather_kernel(idx_ref, pool_ref, out_ref):
-    del idx_ref                      # consumed by the input index map
-    out_ref[...] = pool_ref[...]
+def _row_tile(pool) -> int:
+    """Rows per pool DMA: the height of the chip's (8, 128) 32-bit memory
+    tile in the pool's dtype (16 rows of bf16), cut to a divisor of the
+    pool's row count.  A DMA may only move whole tiles of rows, so the
+    gather and the scatter address a single token row through the aligned
+    tile that holds it.  Serving pools are whole tiles (``n_blocks *
+    chunk`` rows with a 16-token chunk); the cut only matters for the
+    small pools that run in interpret mode."""
+    return math.gcd(8 * max(1, 4 // pool.dtype.itemsize), pool.shape[0])
 
 
-def _scatter_kernel(idx_ref, rows_ref, pool_ref, out_ref):
-    del idx_ref, pool_ref            # idx drives the OUTPUT index map; the
-    out_ref[...] = rows_ref[...]     # pool arrives via the in/out alias
+def _pick_row(block, r):
+    """Row ``r`` (traced) of a (tile, w) block, as (1, w), bit for bit.
+
+    Mosaic has no single-row load at a dynamic offset for packed dtypes,
+    so the row is selected with an iota mask and a max over rows in which
+    every other entry is -inf.  The row comes back in float32, which holds
+    every value of a float32, bf16 or float16 pool exactly."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0) == r
+    picked = jnp.where(hit, block.astype(jnp.float32), -jnp.inf)
+    return jnp.max(picked, axis=0, keepdims=True)
 
 
-def _col_blocks(col_lo: int, width: int):
-    """Column-window blocking for the pool kernels: a *pool offset* inside
-    an interleaved multi-stack token row becomes an extra grid dimension.
+def _window(pool_hbm, row, tile: int, col_lo: int, width: int):
+    """The aligned (tile, width) DMA window of the pool holding ``row``."""
+    start = pl.multiple_of(row - row % tile, tile)
+    return pool_hbm.at[pl.ds(start, tile), pl.ds(col_lo, width)]
 
-    The window [col_lo, col_lo + width) must be LANES-aligned (the
-    LeaseEngine pads every stack's token-row segment to LANES).  When the
-    offset is block-aligned the whole window moves in one DMA per row
-    (``n_cols == 1`` -- the single-pool fast path is unchanged bits);
-    otherwise the window streams in LANES-wide column blocks addressed by
-    the index map's second coordinate.
-    """
-    assert col_lo % LANES == 0 and width % LANES == 0, (col_lo, width)
-    bw = width if col_lo % width == 0 else LANES
-    return bw, width // bw, col_lo // bw
+
+def _gather_kernel(idx_ref, pool_hbm, out_ref, buf, sem, *, tile: int,
+                   col_lo: int, width: int):
+    i = pl.program_id(0)
+    out = jnp.zeros(out_ref.shape, jnp.float32)
+    for k in range(tile):                # static: one output tile per step
+        row = idx_ref[i * tile + k]
+        copy = pltpu.make_async_copy(
+            _window(pool_hbm, row, tile, col_lo, width), buf, sem)
+        copy.start()
+        copy.wait()
+        hit = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) == k
+        out = jnp.where(hit, _pick_row(buf[...], row % tile), out)
+    out_ref[...] = out.astype(out_ref.dtype)
+
+
+def _scatter_kernel(idx_ref, rows_ref, pool_hbm, out_hbm, buf, sem, *,
+                    tile: int, col_lo: int, width: int):
+    del pool_hbm                         # aliased to out_hbm
+    i = pl.program_id(0)
+    row = idx_ref[i]
+    window = _window(out_hbm, row, tile, col_lo, width)
+    load = pltpu.make_async_copy(window, buf, sem)
+    load.start()
+    load.wait()
+    new = _pick_row(rows_ref[...], i % tile).astype(buf.dtype)
+    hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == row % tile
+    buf[...] = jnp.where(hit, new, buf[...])
+    store = pltpu.make_async_copy(buf, window, sem)
+    store.start()
+    store.wait()
 
 
 def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
@@ -316,30 +355,35 @@ def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
     """Scatter ``rows`` into ``pool[idx, col_lo:col_lo+w]``: the append-KV
     path.
 
-    pool (N, W), idx (n,) int32, rows (n, w) with ``col_lo + w <= W``.  The
-    scalar-prefetched ids drive the *output* BlockSpec's index map and the
-    pool buffer is aliased input->output, so each grid step DMAs exactly
-    one updated row (or LANES-wide column block of it) into place and every
-    untouched row -- and every column outside the window -- keeps its bits:
-    a decoded token's KV lands in its page without a host round trip, and a
-    per-stack append touches only that stack's segment of the interleaved
-    token row.  Rows listed twice keep the last write (the grid is
-    sequential).
+    pool (N, W), idx (n,) int32, rows (n, w) with ``col_lo + w <= W``; the
+    window [col_lo, col_lo + w) is LANES-aligned (the LeaseEngine pads
+    every stack's token-row segment to LANES).  The pool stays in HBM,
+    aliased input->output: grid step i reads the aligned tile of rows that
+    holds row ``idx[i]``, replaces that one row's window, and writes the
+    tile back, so every untouched row -- and every column outside the
+    window -- keeps its bits.  A decoded token's KV lands in its page
+    without a host round trip, and a per-stack append touches only that
+    stack's segment of the interleaved token row.  Each step waits for its
+    write before the next step reads, so rows listed twice keep the last
+    write, and rows that share a tile all land.
     """
     n, width = rows.shape
-    bw, n_cols, col0 = _col_blocks(col_lo, width)
+    assert col_lo % LANES == 0 and width % LANES == 0, (col_lo, width)
+    tile = _row_tile(pool)
+    rows = jnp.pad(rows, ((0, (-n) % tile), (0, 0)))
     return pl.pallas_call(
-        _scatter_kernel,
+        functools.partial(_scatter_kernel, tile=tile, col_lo=col_lo,
+                          width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n, n_cols),
+            grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, bw), lambda i, j, idx_ref: (i, j)),
-                pl.BlockSpec((1, bw),
-                             lambda i, j, idx_ref: (idx_ref[i], col0 + j)),
+                pl.BlockSpec((tile, width), lambda i, _idx: (i // tile, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, bw), lambda i, j, idx_ref: (idx_ref[i], col0 + j))),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tile, width), pool.dtype),
+                            pltpu.SemaphoreType.DMA]),
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},       # (scalars, rows, POOL) -> out
         interpret=interpret,
@@ -351,25 +395,30 @@ def gather_rows(pool, idx, *, col_lo: int = 0, width: int = None,
     """Gather ``pool[idx, col_lo:col_lo+width]`` on device: pool (N, W),
     idx (n,) int32.
 
-    The scalar-prefetched ids drive the input BlockSpec's index map, so each
-    grid step DMAs exactly one leased block's payload row -- the paged-KV
+    The scalar-prefetched ids address the pool in HBM: for each id the
+    kernel DMAs the aligned tile of rows that holds it and keeps that one
+    row, filling the output a tile of rows per grid step -- the paged-KV
     materialization path of the serving engine.  ``col_lo``/``width`` name
     a LANES-aligned column window (one stack's segment of an interleaved
-    multi-pool token row); the default gathers the whole row exactly as
-    before.
+    multi-pool token row); the default gathers the whole row.
     """
     n = idx.shape[0]
     if width is None:
         width = pool.shape[1] - col_lo
-    bw, n_cols, col0 = _col_blocks(col_lo, width)
-    return pl.pallas_call(
-        _gather_kernel,
+    assert col_lo % LANES == 0 and width % LANES == 0, (col_lo, width)
+    tile = _row_tile(pool)
+    idx = jnp.pad(jnp.asarray(idx, jnp.int32), (0, (-n) % tile))
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, tile=tile, col_lo=col_lo,
+                          width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n, n_cols),
-            in_specs=[pl.BlockSpec(
-                (1, bw), lambda i, j, idx_ref: (idx_ref[i], col0 + j))],
-            out_specs=pl.BlockSpec((1, bw), lambda i, j, _idx: (i, j))),
-        out_shape=jax.ShapeDtypeStruct((n, width), pool.dtype),
+            grid=(idx.shape[0] // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, width), lambda i, _idx: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((tile, width), pool.dtype),
+                            pltpu.SemaphoreType.DMA]),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], width), pool.dtype),
         interpret=interpret,
-    )(jnp.asarray(idx, jnp.int32), pool)
+    )(idx, pool)
+    return out[:n]

@@ -17,16 +17,19 @@ import jax.numpy as jnp
 from .kernel import (LANES, advance_table, gather_rows, lease_table,
                      lease_table_many, rowmax_table, scatter_rows)
 
+# Table block height: Mosaic tiles int32 as (8, 128), so every table is
+# padded to a multiple of ROWS rows and streams in (ROWS, LANES) blocks.
+ROWS = 8
+
+
+def _table_pad(n: int) -> int:
+    """Padding that makes an (n,) table a whole number of (ROWS, LANES)
+    blocks; padded lanes carry mask == 0, so no kernel result changes."""
+    return (-n) % (ROWS * LANES)
+
 
 def _pad2d(x, pad, fill=0):
     return jnp.pad(x, (0, pad), constant_values=fill).reshape(-1, LANES)
-
-
-def _block_rows(rows: int) -> int:
-    block = 8
-    while rows % block:
-        block //= 2
-    return max(1, block)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -43,7 +46,7 @@ def masked_lease_check(wts, rts, req_wts, mask, pts, lease,
     ``new_pts``.
     """
     n = wts.shape[0]
-    pad = (-n) % LANES
+    pad = _table_pad(n)
     wts2 = _pad2d(wts, pad)
     rts2 = _pad2d(rts, pad)
     req2 = _pad2d(req_wts, pad)
@@ -53,7 +56,7 @@ def masked_lease_check(wts, rts, req_wts, mask, pts, lease,
         lease2 = _pad2d(lease2, pad)
     new_rts, flags, rowmax_rts, rowmax_wts = lease_table(
         wts2, rts2, req2, mask2, pts, lease2,
-        block_rows=_block_rows(wts2.shape[0]), interpret=interpret)
+        block_rows=ROWS, interpret=interpret)
     return {
         "new_rts": new_rts.reshape(-1)[:n],
         "renew_ok": (flags.reshape(-1)[:n] & 1).astype(bool),
@@ -80,7 +83,7 @@ def masked_lease_check_many(wts, rts, req_wts, masks, pts_vec, lease,
     """
     n = wts.shape[0]
     g = masks.shape[0]
-    pad = (-n) % LANES
+    pad = _table_pad(n)
     wts2 = _pad2d(wts, pad)
     rts2 = _pad2d(rts, pad)
     req2 = _pad2d(req_wts, pad)
@@ -90,7 +93,7 @@ def masked_lease_check_many(wts, rts, req_wts, masks, pts_vec, lease,
         lease2 = _pad2d(lease2, pad)
     new_rts, flags, rowmax_rts, rowmax_wts = lease_table_many(
         wts2, rts2, req2, masks2, pts_vec, lease2,
-        block_rows=_block_rows(wts2.shape[0]), interpret=interpret)
+        block_rows=ROWS, interpret=interpret)
     flags_flat = flags.reshape(g, -1)[:, :n]
     return {
         "new_rts": new_rts.reshape(-1)[:n],
@@ -112,15 +115,14 @@ def write_advance(wts, rts, mask, pts, interpret: bool = False):
     Returns (new_wts, new_rts, ts), all int32.
     """
     n = wts.shape[0]
-    pad = (-n) % LANES
+    pad = _table_pad(n)
     wts2 = _pad2d(wts, pad)
     rts2 = _pad2d(rts, pad)
     mask2 = _pad2d(mask, pad)
-    rows = _block_rows(wts2.shape[0])
-    rowmax_rts = rowmax_table(rts2, mask2, block_rows=rows,
+    rowmax_rts = rowmax_table(rts2, mask2, block_rows=ROWS,
                               interpret=interpret)
     ts = jnp.maximum(jnp.asarray(pts, jnp.int32), jnp.max(rowmax_rts) + 1)
-    new_wts, new_rts = advance_table(wts2, rts2, mask2, ts, block_rows=rows,
+    new_wts, new_rts = advance_table(wts2, rts2, mask2, ts, block_rows=ROWS,
                                      interpret=interpret)
     return new_wts.reshape(-1)[:n], new_rts.reshape(-1)[:n], ts
 

@@ -115,12 +115,8 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
     params = _attach(params, pshard)
     specs = input_specs(cfg, shape)
 
-    # ambient mesh so activation sharding constraints (dist.annotate) bind;
-    # use_mesh is the documented context manager on newer jax (set_mesh is a
-    # global setter, not a context manager), Mesh itself works on legacy jax
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    mesh_ctx = use_mesh(mesh) if use_mesh is not None else mesh
-    with mesh_ctx:
+    # ambient mesh so activation sharding constraints (dist.annotate) bind
+    with jax.set_mesh(mesh):
         return _lower_and_analyze(cfg, shape, mesh, rec, params, pshard,
                                   specs, t0, collect_hlo)
 

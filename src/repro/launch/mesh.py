@@ -8,12 +8,14 @@ import; real deployments get the same shapes from the TPU runtime.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes, devices) -> Mesh:
-    # no axis_types: Auto is the default on every jax that accepts it.
-    return jax.make_mesh(shape, axes, devices=devices)
+    # Auto axes: the compiler propagates shardings from the parameter and
+    # activation constraints (jax.make_mesh defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -1,8 +1,11 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Starts a Tardis-coherent replica cluster on the selected architecture's
-reduced config and serves synthetic batched requests (the full configs are
-exercised by the multi-pod dry-run; see repro.launch.dryrun).
+Starts a Tardis-coherent replica cluster on the selected architecture and
+serves synthetic batched requests.  By default the config is cut to smoke
+scale for the CPU (``reduced``); ``--no-reduced`` serves the published
+config at full width, which wants a chip (``chip_smoke.py`` at the
+checkout's root drives tinyllama-1.1b that way, through
+:func:`parse_args` / :func:`build` / :func:`serve`).
 
 ``--hosts K`` serves through K simulated hosts sharing one sharded lease
 directory: the request stream is served in two phases (host 0 first, then
@@ -20,11 +23,16 @@ from ..configs import ARCHS, get_arch, reduced
 from ..core import CONSISTENCY_MODELS, CoherencePolicy
 from ..models import init_params
 from ..runtime import MultiHostServingCluster, Request, ServingCluster
+from .compile_cache import enable_compile_cache
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-scale config for the CPU (default); "
+                         "--no-reduced serves the published config")
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
@@ -59,7 +67,20 @@ def main():
                     help="'min:max' bounds for the per-block lease "
                          "predictor; turns adaptive (Tardis 2.0) lease "
                          "prediction on")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    args.roles = [r.strip() for r in args.roles.split(",") if r.strip()]
+    if args.roles:
+        if args.hosts > 1 and args.hosts != len(args.roles):
+            raise SystemExit(
+                f"--hosts {args.hosts} != {len(args.roles)} roles in --roles")
+        args.hosts = len(args.roles)
+    return args
+
+
+def build(args: argparse.Namespace):
+    """The config, a cluster holding freshly initialized weights (seed 0,
+    float32), and the seeded request stream -- nothing served yet.
+    Returns ``(cfg, cluster, requests)``."""
     if args.lease_bounds:
         lo, _, hi = args.lease_bounds.partition(":")
         policy = CoherencePolicy(
@@ -68,14 +89,9 @@ def main():
     else:
         policy = CoherencePolicy(consistency=args.consistency,
                                  lease=args.kv_lease)
-    roles = [r.strip() for r in args.roles.split(",") if r.strip()]
-    if roles:
-        if args.hosts > 1 and args.hosts != len(roles):
-            raise SystemExit(
-                f"--hosts {args.hosts} != {len(roles)} roles in --roles")
-        args.hosts = len(roles)
-
-    cfg = reduced(get_arch(args.arch))
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
     if cfg.family == "encdec":
         raise SystemExit("serve CLI drives decoder-only archs; whisper is "
                          "exercised via tests/dry-run (needs frame inputs)")
@@ -92,32 +108,47 @@ def main():
                 [system, rng.integers(1, cfg.vocab, rng.integers(4, 16))
                  .astype(np.int32)]), max_new=args.max_new)
             for i in range(args.requests)]
+    roles = args.roles
+    if args.hosts > 1:
+        cluster = MultiHostServingCluster(
+            cfg, lambda: params, n_hosts=args.hosts,
+            n_shards=args.shards or None, roles=roles or None, **kw)
+    else:
+        cluster = ServingCluster(cfg, lambda: params, **kw)
+    return cfg, cluster, reqs
+
+
+def serve(args: argparse.Namespace, cluster, reqs):
+    """Serve ``reqs`` the way the flags say; returns ``(requests,
+    coherence report)``."""
+    roles = args.roles
     if roles and any(r != "mixed" for r in roles):
         # disaggregated fleet: ONE routed run -- the admission router
         # forwards cold prefixes to the prefill pods, decode pods serve
         # the handed-back streams suffix-only (default decode affinity)
-        cluster = MultiHostServingCluster(
-            cfg, lambda: params, n_hosts=args.hosts,
-            n_shards=args.shards or None, roles=roles, **kw)
-        done, report = cluster.run(reqs)
-    elif args.hosts > 1:
-        cluster = MultiHostServingCluster(
-            cfg, lambda: params, n_hosts=args.hosts,
-            n_shards=args.shards or None, roles=roles or None, **kw)
+        return cluster.run(reqs)
+    if args.hosts > 1:
         # phase 1: host 0 prefills + publishes the shared prefix; phase 2:
         # the other hosts serve the same system prompt suffix-only
         n0 = max(1, len(reqs) // args.hosts)
         cluster.run(reqs[:n0], affinity=[0] * n0)
-        done, report = cluster.run(
+        _, report = cluster.run(
             reqs[n0:],
             affinity=[1 + i % (args.hosts - 1)
                       for i in range(len(reqs) - n0)])
-        done = reqs
-    else:
-        cluster = ServingCluster(cfg, lambda: params, **kw)
-        done, report = cluster.run(reqs)
+        return reqs, report
+    return cluster.run(reqs)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    _, cluster, reqs = build(args)
+    done, report = serve(args, cluster, reqs)
+    roles = args.roles
+    width = "reduced" if args.reduced else "full width"
     print(f"served {len(done)} requests on {args.replicas} replicas x "
-          f"{args.hosts} host(s) ({args.arch} reduced)")
+          f"{args.hosts} host(s) ({args.arch} {width})")
     for k, v in report.items():
         print(f"  {k:28s} {v}")
     if report["prefix_prefill_tokens_skipped"]:

@@ -1,8 +1,8 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-On real hardware this process runs per-host under the TPU runtime with the
-production mesh; in this environment it runs reduced configs on CPU with the
-same code path (config -> params -> sharded step -> fault-tolerant loop).
+Runs config -> params -> step -> fault-tolerant loop on one device.  By
+default the config is cut to smoke scale for the CPU (``reduced``);
+``--no-reduced`` trains the published config, which wants a chip.
 """
 import argparse
 
@@ -12,21 +12,25 @@ import jax.numpy as jnp
 from ..configs import ARCHS, get_arch, reduced
 from ..models import init_params
 from ..runtime import TrainConfig, train
+from .compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt-dir", default="")
-    ap.add_argument("--reduced", action="store_true", default=True,
-                    help="use the smoke-scale config (full configs need TPU)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-scale config for the CPU (default); "
+                         "--no-reduced trains the published config")
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--n-micro", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -953,12 +953,15 @@ class ServingCluster:
         in_use = self.n_decode_pages - eng.free_page_count()
         ps["pool_page_peak"] = max(ps["pool_page_peak"], in_use)
         if self.trace is not None:
+            # the pool rows backing the whole prompt, in position order
+            at = np.arange(plen)
+            prompt_idx = page_row[at // bt].astype(np.int64) * bt + at % bt
             self.trace.append({
                 "ev": "admit", "tick": tick, "rep": rep.name,
                 "rid": req.rid, "prompt_len": plen, "skip": skip,
                 "page_row": page_row.copy(), "pages": total_pages,
                 "logits": np.asarray(logits).copy(),
-                "rows": np.asarray(eng.kv_rows_view()).copy()})
+                "rows": np.asarray(eng.kv_rows_view()[prompt_idx])})
         if stream.finished:
             self._finalize(stream)
             return None

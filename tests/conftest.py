@@ -1,9 +1,5 @@
-"""Test-suite bootstrap: make the suite collect in hermetic environments.
-
-* Puts ``src/`` on sys.path so ``PYTHONPATH=src`` is not required.
-* Installs the deterministic ``hypothesis`` shim when the real package is
-  unavailable (no package index in CI containers).
-"""
+"""Test-suite bootstrap: puts ``src/`` on sys.path so ``PYTHONPATH=src``
+is not required."""
 import os
 import sys
 
@@ -13,10 +9,3 @@ if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)          # for ``import benchmarks.analytic``
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_compat
-    _hypothesis_compat.install()

@@ -124,7 +124,7 @@ class TestAnnotate:
     def test_constrains_under_ambient_mesh(self):
         mesh = make_host_mesh(data=1, model=1)
         x = jnp.ones((4, 8, 16))
-        with mesh:
+        with jax.set_mesh(mesh):
             assert annotate.ambient_mesh() is not None
             y = jax.jit(annotate.batch_activations)(x)
             z = jax.jit(annotate.replicate)(x)
@@ -137,7 +137,7 @@ class TestAnnotate:
         def f(x):
             return jnp.sum(annotate.batch_activations(x) ** 2)
         x = jnp.arange(8.0).reshape(2, 4)
-        with mesh:
+        with jax.set_mesh(mesh):
             g = jax.grad(f)(x)
         np.testing.assert_allclose(np.asarray(g), 2 * np.asarray(x))
 

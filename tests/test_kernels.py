@@ -14,10 +14,15 @@ from repro.kernels.rmsnorm.ops import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
-from repro.kernels.tardis_lease.ops import (append_rows, lease_check,
+from repro.kernels.tardis_lease.ops import (append_rows, gather_blocks,
+                                            lease_check,
                                             masked_lease_check,
+                                            masked_lease_check_many,
                                             write_advance)
-from repro.kernels.tardis_lease.ref import (append_rows_ref, lease_check_ref,
+from repro.kernels.tardis_lease.ref import (append_rows_ref,
+                                            gather_blocks_ref,
+                                            lease_check_ref,
+                                            masked_lease_check_many_ref,
                                             masked_lease_check_ref,
                                             write_advance_ref)
 
@@ -167,6 +172,68 @@ def test_append_rows_scatter_kernel(n, w, rows_w):
     ref = np.asarray(append_rows_ref(pool, idx, rows))
     out = append_rows(pool, idx, rows, interpret=True)   # donates pool
     np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+@pytest.mark.parametrize("n", [4352, 4608])     # 34 and 36 rows of 128
+@pytest.mark.parametrize("lease", ["static", "predicted"])
+def test_tardis_table_kernels_unaligned_rows(n, lease):
+    """Serving-size tables whose row count is not a multiple of the 8-row
+    block: the padded kernels stay bit-identical to the protocol refs."""
+    rng = np.random.default_rng(n)
+    wts = jnp.asarray(rng.integers(0, 100, n), jnp.int32)
+    rts = jnp.maximum(wts, jnp.asarray(rng.integers(0, 120, n), jnp.int32))
+    req = jnp.where(jnp.asarray(rng.random(n) < 0.5), wts, wts - 1)
+    masks = jnp.asarray(rng.integers(0, 2, (3, n)), jnp.int32)
+    pts = jnp.asarray([40, 55, 70], jnp.int32)
+    ls = (jnp.asarray(rng.integers(4, 64, n), jnp.int32)
+          if lease == "predicted" else jnp.int32(10))
+    out = masked_lease_check(wts, rts, req, masks[0], pts[0], ls,
+                             interpret=True)
+    ref = masked_lease_check_ref(wts, rts, req, masks[0], pts[0], ls)
+    for k in ("new_rts", "renew_ok", "expired", "write_ts", "new_pts"):
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(ref[k]),
+                                      err_msg=k)
+    out = masked_lease_check_many(wts, rts, req, masks, pts, ls,
+                                  interpret=True)
+    ref = masked_lease_check_many_ref(wts, rts, req, masks, pts, ls)
+    for k in ("new_rts", "renew_ok", "expired", "write_ts", "new_pts"):
+        np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(ref[k]),
+                                      err_msg=k)
+    w1, r1, t1 = write_advance(wts, rts, masks[1], pts[1], interpret=True)
+    w2, r2, t2 = write_advance_ref(wts, rts, masks[1], pts[1])
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
+    np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
+    assert int(t1) == int(t2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n_rows", [32, 12])
+def test_moe_dual_stack_column_window_gather_scatter(dtype, n_rows):
+    """One stack's column window of the moe family's interleaved token row
+    (leading dense stack, then moe stack): the gather returns exactly that
+    window, and the append writes it while every other column and row --
+    the neighbor stack's segment included -- keeps its bits."""
+    from repro.configs import get_arch, reduced
+    from repro.models import pool_layout
+    stacks = pool_layout(reduced(get_arch("kimi-k2-1t-a32b")))
+    assert [s.pool for s in stacks] == ["dense", "moe"]
+    last = stacks[-1]
+    token_row = last.offset + -(-last.token_elems // 128) * 128
+    rng = np.random.default_rng(n_rows)
+    pool = jnp.asarray(rng.standard_normal((n_rows, token_row)), dtype)
+    idx = jnp.asarray([3, 0, n_rows - 1, 3, 5], jnp.int32)    # duplicate id
+    for s in stacks:
+        width = -(-s.token_elems // 128) * 128
+        got = gather_blocks(pool, idx, col_lo=s.offset, width=width,
+                            interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(gather_blocks_ref(
+                pool, idx, s.offset, width)))
+        rows = jnp.asarray(rng.standard_normal((5, s.token_elems)), dtype)
+        ref = np.asarray(append_rows_ref(pool, idx, rows, s.offset, width))
+        out = append_rows(jnp.array(pool), idx, rows, col_lo=s.offset,
+                          width=width, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), ref)
 
 
 def test_decode_attention_routing():

@@ -93,10 +93,7 @@ def _replay_dense_shadow(arch, cluster, trace):
     for ev in trace:
         if ev["ev"] == "admit":
             plen = ev["prompt_len"]
-            pos = np.arange(plen)
-            flat = (ev["page_row"][pos // bt].astype(np.int64) * bt
-                    + pos % bt)
-            rows = ev["rows"][flat]                      # (plen, token_row)
+            rows = ev["rows"]                            # (plen, token_row)
             c = {}
             for s in stacks:
                 kv = rows[:, s.offset:s.offset + s.token_elems].reshape(
