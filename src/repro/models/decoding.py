@@ -260,31 +260,41 @@ def decode_step_paged(cfg: ArchConfig, p: Params, pool_rows, page_rows,
                 # scan bodies but not in moe's leading dense stack --
                 # mirror it exactly (replicate is numerically identity)
                 x = replicate(x)
-            y, kd, vd = attn_decode_paged(
-                layer["attn"], cfg, x, pool_rows, page_rows, lengths,
-                li * hkd, (spec.n_layers + li) * hkd, pool_off=spec.offset,
-                chunk=chunk, interpret=interpret, use_kernel=use_kernel)
-            x = x + y
-            xn = rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
-            if spec.kind == "moe":
-                x = x + moe_apply(layer["moe"], cfg, xn)
-            else:
-                x = x + swiglu(layer["mlp"], xn)
-            k_off = spec.offset + li * hkd
-            v_off = spec.offset + (spec.n_layers + li) * hkd
-            row_buf = row_buf.at[:, k_off:k_off + hkd].set(
-                kd.reshape(b, hkd))
-            row_buf = row_buf.at[:, v_off:v_off + hkd].set(
-                vd.reshape(b, hkd))
+            with jax.named_scope("attention"):
+                y, kd, vd = attn_decode_paged(
+                    layer["attn"], cfg, x, pool_rows, page_rows, lengths,
+                    li * hkd, (spec.n_layers + li) * hkd,
+                    pool_off=spec.offset, chunk=chunk, interpret=interpret,
+                    use_kernel=use_kernel)
+                x = x + y
+            with jax.named_scope("mlp"):
+                x = x + _mlp(cfg, spec.kind, layer, x)
+            with jax.named_scope("kv_append"):
+                k_off = spec.offset + li * hkd
+                v_off = spec.offset + (spec.n_layers + li) * hkd
+                row_buf = row_buf.at[:, k_off:k_off + hkd].set(
+                    kd.reshape(b, hkd))
+                row_buf = row_buf.at[:, v_off:v_off + hkd].set(
+                    vd.reshape(b, hkd))
     # ONE append per step: the token's whole row (every stack's, every
     # layer's K and V) lands in its page via the scalar-prefetched scatter
     # kernel
-    flat_idx = (page_rows[jnp.arange(b), lengths // chunk] * chunk
-                + lengths % chunk)
-    pool_rows = scatter_rows(pool_rows, flat_idx, row_buf,
-                             interpret=interpret)
-    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
-    return pool_rows, logits_fn(cfg, p, x)
+    with jax.named_scope("kv_append"):
+        flat_idx = (page_rows[jnp.arange(b), lengths // chunk] * chunk
+                    + lengths % chunk)
+        pool_rows = scatter_rows(pool_rows, flat_idx, row_buf,
+                                 interpret=interpret)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        return pool_rows, logits_fn(cfg, p, x)
+
+
+def _mlp(cfg: ArchConfig, kind: str, layer, x):
+    """A layer's feed-forward residual branch (dense or moe)."""
+    xn = rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
+    if kind == "moe":
+        return moe_apply(layer["moe"], cfg, xn)
+    return swiglu(layer["mlp"], xn)
 
 
 # ---------------------------------------------------------------------------
@@ -315,27 +325,22 @@ def prefill(cfg: ArchConfig, p: Params, batch, cache_len: int,
     cache: Cache = {}
 
     if fam in ("dense", "vlm", "moe"):
-        def body(xx, layer):
-            y, (kc, vc) = attn_prefill(layer["attn"], cfg, xx, positions,
-                                       cache_len)
-            xx = xx + y
-            xn = rmsnorm(xx, layer["mlp_norm"], cfg.norm_eps)
-            if fam == "moe":
-                xx = xx + moe_apply(layer["moe"], cfg, xn)
-            else:
-                xx = xx + swiglu(layer["mlp"], xn)
-            return xx, (kc.astype(dtype), vc.astype(dtype))
+        def make_body(kind):
+            def body(xx, layer):
+                with jax.named_scope("attention"):
+                    y, (kc, vc) = attn_prefill(layer["attn"], cfg, xx,
+                                               positions, cache_len)
+                    xx = xx + y
+                with jax.named_scope("mlp"):
+                    xx = xx + _mlp(cfg, kind, layer, xx)
+                return xx, (kc.astype(dtype), vc.astype(dtype))
+            return body
         if fam == "moe" and cfg.first_dense_layers:
-            def dbody(xx, layer):
-                y, (kc, vc) = attn_prefill(layer["attn"], cfg, xx, positions,
-                                           cache_len)
-                xx = xx + y
-                xn = rmsnorm(xx, layer["mlp_norm"], cfg.norm_eps)
-                return xx + swiglu(layer["mlp"], xn), (kc.astype(dtype),
-                                                       vc.astype(dtype))
-            x, (dk, dv) = jax.lax.scan(dbody, x, p["dense_layers"])
+            x, (dk, dv) = jax.lax.scan(make_body("mlp"), x,
+                                       p["dense_layers"])
             cache.update(dk=dk, dv=dv)
-        x, (k, v) = jax.lax.scan(body, x, p["layers"])
+        x, (k, v) = jax.lax.scan(make_body("moe" if fam == "moe" else "mlp"),
+                                 x, p["layers"])
         cache.update(k=k, v=v)
     elif fam == "ssm":
         def body(xx, layer):
@@ -365,8 +370,9 @@ def prefill(cfg: ArchConfig, p: Params, batch, cache_len: int,
         cache.update(state=jnp.concatenate(states),
                      conv=jnp.concatenate(convs),
                      ak=jnp.stack(aks), av=jnp.stack(avs))
-    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
-    return cache, _last_logits(cfg, p, x, last_idx)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        return cache, _last_logits(cfg, p, x, last_idx)
 
 
 def prefill_suffix(cfg: ArchConfig, p: Params, batch, cache: Cache,
@@ -396,14 +402,13 @@ def prefill_suffix(cfg: ArchConfig, p: Params, batch, cache: Cache,
     def make_body(kind):
         def body(xx, xs):
             layer, kc, vc = xs
-            y, kc, vc = attn_prefill_cached(layer["attn"], cfg, xx,
-                                            positions, kc, vc, prefix_len)
-            xx = xx + y
-            xn = rmsnorm(xx, layer["mlp_norm"], cfg.norm_eps)
-            if kind == "moe":
-                xx = xx + moe_apply(layer["moe"], cfg, xn)
-            else:
-                xx = xx + swiglu(layer["mlp"], xn)
+            with jax.named_scope("attention"):
+                y, kc, vc = attn_prefill_cached(layer["attn"], cfg, xx,
+                                                positions, kc, vc,
+                                                prefix_len)
+                xx = xx + y
+            with jax.named_scope("mlp"):
+                xx = xx + _mlp(cfg, kind, layer, xx)
             return xx, (kc, vc)
         return body
 
@@ -413,8 +418,9 @@ def prefill_suffix(cfg: ArchConfig, p: Params, batch, cache: Cache,
         x, (k, v) = jax.lax.scan(make_body(spec.kind), x,
                                  (p[spec.params_key], cache[ck], cache[cv]))
         out[ck], out[cv] = k, v
-    x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
-    return out, _last_logits(cfg, p, x, last_idx)
+    with jax.named_scope("head"):
+        x = rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        return out, _last_logits(cfg, p, x, last_idx)
 
 
 def _encdec_prefill(cfg, p, batch, cache_len, dtype=jnp.bfloat16):

@@ -58,6 +58,7 @@ a directory-style invalidation broadcast on the same request stream.
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 import zlib
 from collections import deque
@@ -71,13 +72,24 @@ from ..core.lease_engine import LeaseEngine
 from ..core.policy import CoherencePolicy, resolve_policy
 from ..core.shard_directory import ShardedLeaseDirectory
 from ..core.store import Replica, TardisStore
+from .. import models
 from ..models import (PAGED_FAMILIES, decode_step, decode_step_paged,
-                      pool_layout, prefill, prefill_suffix)
+                      pool_layout, prefill)
+from .spans import Spans
 
 # families whose prefill KV cache is position-addressable block-wise, i.e.
 # can be carried through the paged KV pool (an SSM state cannot); the moe
 # family pages both of its cache stacks through named pools.
 KV_POOL_FAMILIES = PAGED_FAMILIES
+
+# the serving loop's host spans (``span_<name>_ns`` / ``span_<name>_n``):
+# a whole admission group and, inside it, the lease wave, the leased
+# prefix's materialisation, the wait on the prefill, the suffix KV's trip
+# through the host and its append; a decode step and, inside it, the
+# decode-lease renewals and the wait on the step; the per-tick rebase check
+SPAN_NAMES = ("admit", "lease_wave", "pool_read", "prefill_wait",
+              "kv_to_host", "kv_append", "decode", "renew", "decode_wait",
+              "rebase")
 
 
 @dataclasses.dataclass
@@ -87,6 +99,9 @@ class Request:
     max_new: int = 8
     done: bool = False
     output: Optional[np.ndarray] = None
+    # when the request joined the program's queue (admission's queue wait
+    # is measured from here)
+    t_queued: int = dataclasses.field(default_factory=time.perf_counter_ns)
 
 
 @dataclasses.dataclass
@@ -216,48 +231,6 @@ def _prefix_cache(stacks, pkv, batch, cache_len: int, skip: int):
     return cache
 
 
-class CoherenceReport(dict):
-    """The coherence ledger: the legacy flat counter dict plus typed group
-    accessors, so callers address a whole namespace (``report.xhost``,
-    ``report.role``, ``report.router``, ``report.lease``) instead of
-    string-matching individual key names.  Every flat key is preserved --
-    the accessors are read-only views over the same entries.
-    """
-
-    # the lease-protocol namespace has historical un-prefixed names; the
-    # accessor gathers them so new call sites never hard-code the list
-    _LEASE_KEYS = (
-        "kv_lease", "consistency", "renewals", "data_less_renewals",
-        "prefix_renewals", "prefix_local_hits",
-        "prefix_data_less_renewals", "decode_renewals",
-        "decode_renewals_skipped", "decode_local_hits", "pred_grows",
-        "pred_shrinks", "pred_lease_lo", "pred_lease_hi")
-
-    def _ns(self, prefix: str) -> Dict[str, Any]:
-        return {k[len(prefix):]: self[k]
-                for k in self if k.startswith(prefix)}
-
-    @property
-    def lease(self) -> Dict[str, Any]:
-        """Lease-protocol group: renewals, local hits, predictor state."""
-        return {k: self[k] for k in self._LEASE_KEYS if k in self}
-
-    @property
-    def xhost(self) -> Dict[str, Any]:
-        """Cross-host group: the ``xhost_*`` directory/migration ledger."""
-        return self._ns("xhost_")
-
-    @property
-    def role(self) -> Dict[str, Any]:
-        """Per-role group: the ``role_*`` disaggregation ledger."""
-        return self._ns("role_")
-
-    @property
-    def router(self) -> Dict[str, Any]:
-        """Admission-router group: the ``router_*`` ledger."""
-        return self._ns("router_")
-
-
 class ServingCluster:
     """N replicas + weight publisher + shared paged-KV LeaseEngine pool."""
 
@@ -365,7 +338,11 @@ class ServingCluster:
             "role_cold_prefills": 0, "role_suffix_admissions": 0,
             "role_pages_published": 0, "role_prefill_jobs": 0,
             "role_renewal_msgs": 0, "decode_ticks": 0,
+            # admission: requests admitted (prefill-pod shadows excluded)
+            # and the time they waited in the queue before it
+            "requests_admitted": 0, "queue_wait_ns": 0,
         }
+        self._span = Spans(self.prefix_stats, SPAN_NAMES)
         # disaggregated serving role: "mixed" (default, serves everything),
         # "prefill" (only admits cold prefixes, publishes, never decodes),
         # or "decode" (never prefills a cold prefix; cold work is routed
@@ -382,23 +359,31 @@ class ServingCluster:
         if self.paged:
             interp = self.prefix_engine.interpret
             ch = self.prefix_block_tokens
-            self._decode_paged_fn = jax.jit(
-                lambda p, pool, pr, ln, tk: decode_step_paged(
-                    cfg, p, pool, pr, ln, tk, chunk=ch, interpret=interp),
-                donate_argnums=(1,))
+            stacks = self._stacks
+
+            # the three serving programs, named: their XLA modules (and
+            # the ops a profiler trace shows) carry these names
+
+            def decode_paged(p, pool, pr, ln, tk):
+                return decode_step_paged(cfg, p, pool, pr, ln, tk, chunk=ch,
+                                         interpret=interp)
+
+            def prefill(p, b, cl, li):
+                return models.prefill(cfg, p, b, cl, last_idx=li)
+
+            def prefill_suffix(p, b, pkv, n, cl, li):
+                return models.prefill_suffix(
+                    cfg, p, b,
+                    _prefix_cache(stacks, pkv, b["tokens"].shape[0], cl, n),
+                    n, last_idx=li)
+
+            self._decode_paged_fn = jax.jit(decode_paged,
+                                            donate_argnums=(1,))
             # admission prefills are right-padded to a block multiple with
             # the true last position a traced index, so retraces are
             # bounded by (cache_len, skip) buckets, not request lengths
-            self._prefill_fn = jax.jit(
-                lambda p, b, cl, li: prefill(cfg, p, b, cl, last_idx=li),
-                static_argnums=2)
-            stacks = self._stacks
-            self._psuffix_fn = jax.jit(
-                lambda p, b, pkv, n, cl, li: prefill_suffix(
-                    cfg, p, b,
-                    _prefix_cache(stacks, pkv, b["tokens"].shape[0], cl, n),
-                    n, last_idx=li),
-                static_argnums=(3, 4))
+            self._prefill_fn = jax.jit(prefill, static_argnums=2)
+            self._psuffix_fn = jax.jit(prefill_suffix, static_argnums=(3, 4))
 
     def attach_directory(self, directory, host_id: int) -> None:
         """Join a sharded lease directory as host ``host_id``.
@@ -510,8 +495,14 @@ class ServingCluster:
         prompt collapse to 1 read + <=1 write instead of N full-table
         dispatch pairs.  No invalidation reaches other replicas.
         """
-        if self.directory is not None:
-            return self._lease_prefix_wave_dir(rep, prompts)
+        with self._span("lease_wave"):
+            if self.directory is not None:
+                return self._lease_prefix_wave_dir(rep, prompts)
+            return self._lease_prefix_wave_local(rep, prompts)
+
+    def _lease_prefix_wave_local(self, rep: DecodeReplica,
+                                 prompts: List[np.ndarray]) -> WavePlan:
+        """Single-host prefix leasing against the local engine's tables."""
         rep.kv_pts += 1
         ps = self.prefix_stats
         bt = self.prefix_block_tokens
@@ -728,10 +719,11 @@ class ServingCluster:
     def _maybe_rebase(self) -> None:
         if self.directory is not None:
             return        # the multi-host coordinator drives rebases
-        shift = self.prefix_engine.maybe_rebase()
-        if shift:
-            for rep in self.replicas:
-                rep.rebase_kv(shift)
+        with self._span("rebase"):
+            shift = self.prefix_engine.maybe_rebase()
+            if shift:
+                for rep in self.replicas:
+                    rep.rebase_kv(shift)
 
     # -- paged-KV pool <-> per-stack cache layout ---------------------------
 
@@ -823,8 +815,19 @@ class ServingCluster:
             joiners.append(queue.popleft())
         if not joiners:
             return
-        if act:
-            self.prefix_stats["paged_mid_batch_admissions"] += len(joiners)
+        with self._span("admit"):
+            ps = self.prefix_stats
+            t_admit = time.perf_counter_ns()
+            ps["requests_admitted"] += len(joiners)
+            ps["queue_wait_ns"] += sum(t_admit - j.t_queued for j in joiners)
+            if act:
+                ps["paged_mid_batch_admissions"] += len(joiners)
+            self._admit_group(rep, joiners, act, tick)
+
+    def _admit_group(self, rep: DecodeReplica, joiners: List[Request],
+                     act: List[Stream], tick: int) -> None:
+        """One lease wave for the whole group, then each joiner's prefill;
+        the streams that did not finish at their first token join ``act``."""
         # the joiners' pages are promised: a relocation triggered by this
         # very plan's evictions may not starve their allocation
         self._admit_reserved = sum(self._pages_needed(j) for j in joiners)
@@ -893,8 +896,9 @@ class ServingCluster:
                             self.host_id, bid,           # be current
                             int(self.directory.tags[bid]))
             if key not in mat_cache:
-                mat_cache[key] = self._pool_to_stack_kv(
-                    self._read_kv_stacks(list(key)))
+                with self._span("pool_read"):
+                    mat_cache[key] = self._pool_to_stack_kv(
+                        self._read_kv_stacks(list(key)))
             cache, logits = self._psuffix_fn(params, {"tokens": toks},
                                              mat_cache[key], skip,
                                              cache_len, last)
@@ -942,10 +946,16 @@ class ServingCluster:
         if own:
             self._pool_wver[own] = -1 if wver is None else int(wver)
         # the prompt's suffix KV lands in the own pages, token-granular
-        rows = self._cache_token_rows(cache, skip, plen)
+        # (the host reads the cache here: wait for the prefill first, so
+        # the wait and the copy are told apart)
+        with self._span("prefill_wait"):
+            jax.block_until_ready((cache, logits))
+        with self._span("kv_to_host"):
+            rows = self._cache_token_rows(cache, skip, plen)
         pos = np.arange(skip, plen)
         flat_idx = (page_row[pos // bt].astype(np.int64) * bt + pos % bt)
-        eng.append_kv(flat_idx, rows)
+        with self._span("kv_append"):
+            eng.append_kv(flat_idx, rows)
         t0 = int(np.argmax(np.asarray(logits[0, -1])))
         stream = Stream(req=req, page_row=page_row, own_pages=own,
                         shared_bids=list(bids[:covered]), reloc_pages=[],
@@ -1012,18 +1022,12 @@ class ServingCluster:
                 shadows.append(shadow)
             if not shadows:
                 continue
-            self._admit_reserved = sum(self._pages_needed(s)
-                                       for s in shadows)
-            params = rep.params()
-            wver = rep.reader.cached_version("params")
-            plan = self._lease_prefix_wave(rep, [s.prompt for s in shadows])
-            mat_cache: Dict[Tuple[int, ...], Tuple] = {}
-            for ji, shadow in enumerate(shadows):
-                self._admit_reserved -= self._pages_needed(shadow)
-                s = self._admit_one(rep, shadow, plan, ji, params, wver,
-                                    mat_cache, tick)
-                assert s is None, "max_new=0 shadow must finalize inline"
-            self._admit_reserved = 0
+            # the shadows' admission group: an ``admit`` span, but no
+            # admitted requests or queue wait (they are no requests)
+            left: List[Stream] = []
+            with self._span("admit"):
+                self._admit_group(rep, shadows, left, tick)
+            assert not left, "max_new=0 shadow must finalize inline"
             ps["role_prefill_jobs"] += len(jobs)
             done.extend(jobs)
         return done
@@ -1137,7 +1141,8 @@ class ServingCluster:
         eng = self.prefix_engine
         rep.kv_pts += 1                   # the tick is one logical step
         self.prefix_stats["decode_ticks"] += 1
-        self._renew_decode_leases(rep, act)
+        with self._span("renew"):
+            self._renew_decode_leases(rep, act)
         bt = self.prefix_block_tokens
         page_rows = np.stack([s.page_row for s in act])
         lengths = np.asarray([s.length for s in act], np.int32)
@@ -1152,7 +1157,8 @@ class ServingCluster:
         eng.set_kv_rows(pool, tokens_appended=len(act))
         self.prefix_stats["decode_block_reads"] += int(
             sum(-(-(int(n) + 1) // bt) for n in lengths))
-        nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
+        with self._span("decode_wait"):
+            nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
         if self.trace is not None:
             self.trace.append({
                 "ev": "tick", "tick": tick, "rep": rep.name,
@@ -1189,7 +1195,8 @@ class ServingCluster:
             self._admit(r, rep, queues[r], self._active[r], tick)
         for r, rep in enumerate(self.replicas):
             if self._active[r]:
-                self._decode_tick(rep, self._active[r], tick)
+                with self._span("decode"):
+                    self._decode_tick(rep, self._active[r], tick)
         self._maybe_rebase()
 
     def _run_paged(self, requests: List[Request]) -> None:
@@ -1234,6 +1241,8 @@ class ServingCluster:
         return requests, self.coherence_report()
 
     def coherence_report(self) -> Dict[str, Any]:
+        """The flat counter dict: protocol traffic, pool occupancy, the
+        serving loop's spans and admission counters, and config scalars."""
         s = self.store.stats
         e = self.prefix_engine.stats
         saved = s.renew_data_less * self.param_bytes
@@ -1241,7 +1250,7 @@ class ServingCluster:
         # local hits never generate a message at all -- ledger them apart
         local_saved = (self.prefix_stats["prefix_local_hits"]
                        * self.prefix_engine.block_bytes)
-        return CoherenceReport({
+        return {
             "reads": s.reads, "writes": s.writes,
             "renewals": s.renews + e.renewals,
             "data_less_renewals": s.renew_data_less + e.data_less,
@@ -1289,7 +1298,7 @@ class ServingCluster:
             "consistency": self.policy.consistency,
             "n_prefix_blocks": self.n_prefix_blocks,
             "role": self.role,
-        })
+        }
 
 
 class MultiHostServingCluster:
@@ -1609,4 +1618,4 @@ class MultiHostServingCluster:
         agg["n_hosts"] = len(self.hosts)
         agg.update(self._route_stats)
         agg.update(self.directory.report())
-        return CoherenceReport(agg)
+        return agg

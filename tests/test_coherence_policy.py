@@ -2,9 +2,8 @@
 
 Covers the policy dataclass itself (validation, predictor bounds, the
 grow/shrink step rules every engine shares), the serving-cluster
-deprecation shim for the legacy ``kv_lease=``/``ts_bits=`` kwargs, the
-typed ``CoherenceReport`` accessor groups, and the adaptive-lease state
-machine end to end: predictions survive ``ts_bits`` rebases unshifted
+deprecation shim for the legacy ``kv_lease=``/``ts_bits=`` kwargs, and
+the adaptive-lease state machine end to end: predictions survive ``ts_bits`` rebases unshifted
 (they are timestamp *deltas*), travel with pages across shard-directory
 migration, evolve bit-identically to a single-engine oracle under sharded
 waves, and match between the Pallas kernels and the numpy mirror.
@@ -114,24 +113,6 @@ def test_policy_kwarg_is_silent_and_exclusive():
     with pytest.raises(ValueError, match="not both"):
         _tiny_cluster(n_replicas=1, prefix_block_tokens=4,
                       policy=pol, kv_lease=16)
-
-
-def test_coherence_report_typed_accessors_keep_flat_keys():
-    pol = CoherencePolicy(consistency="tso", lease=16, predictor=True)
-    cluster = _tiny_cluster(n_replicas=1, prefix_block_tokens=4, policy=pol)
-    rep = cluster.coherence_report()
-    assert isinstance(rep, dict)                       # flat view intact
-    assert rep["consistency"] == "tso"
-    assert rep["kv_lease"] == 16
-    lease = rep.lease
-    assert lease["consistency"] == "tso"
-    assert {"renewals", "decode_renewals_skipped", "pred_grows",
-            "pred_shrinks"} <= set(lease)
-    assert all(k in rep for k in lease)                # group == flat subset
-    for group in (rep.xhost, rep.role, rep.router):
-        assert isinstance(group, dict)
-        for k in group:                                # prefixes stripped
-            assert not k.startswith(("xhost_", "role_", "router_"))
 
 
 # ---------------------------------------------------------------------------
