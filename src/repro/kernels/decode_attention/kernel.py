@@ -12,11 +12,16 @@ named by a per-request page-table row of block ids.  The scalar-prefetched
 page tables drive the K/V input index maps -- the same DMA trick as the
 lease engine's ``_gather_kernel`` -- so grid step (b, j) streams request
 b's j-th page straight from the pool with no host round trip and no
-materialized per-request cache.  Per-request ``lengths`` (also prefetched)
-mask the ragged tail; the current decode token's fresh (k, v) ride in as a
-separate operand folded into the accumulators at j == 0, which keeps the
-append-then-attend ordering of the dense path without re-reading the row
-the step just wrote.
+materialized per-request cache.  The pool enters twice, as a K and a V
+operand whose (chunk, hk*dh) blocks are this layer's K and V column
+windows of the page, so a step moves one layer's K/V and not the whole
+token row.  Per-request ``lengths`` (also prefetched) mask the ragged
+tail, and clamp the page index to the request's last page in use: the
+steps past it name the block already fetched, so the pipeline issues no
+DMA for the reserved pages not yet written.  The current decode token's
+fresh (k, v) ride in as a separate operand folded into the accumulators
+at j == 0, which keeps the append-then-attend ordering of the dense path
+without re-reading the row the step just wrote.
 """
 from __future__ import annotations
 
@@ -97,9 +102,9 @@ def decode_attention_grouped(q, k, v, kv_len, *, scale: float,
     )(jnp.asarray(kv_len, jnp.int32).reshape(1), q, k, v)
 
 
-def _paged_decode_kernel(scalars_ref, q_ref, cur_k_ref, cur_v_ref, pool_ref,
-                         o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                         chunk: int, k_off: int, v_off: int, hkd: int,
+def _paged_decode_kernel(scalars_ref, q_ref, cur_k_ref, cur_v_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                         chunk: int, k_lo: int, v_lo: int, hkd: int,
                          num_pages: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -121,27 +126,43 @@ def _paged_decode_kernel(scalars_ref, q_ref, cur_k_ref, cur_v_ref, pool_ref,
         l_scr[...] = jnp.ones_like(s0)
         acc_scr[...] = jnp.broadcast_to(cv, acc_scr.shape)
 
-    # lane-aligned 2-D slices of the page's token rows: (chunk, hk*dh)
-    k = pool_ref[:, k_off:k_off + hkd].astype(jnp.float32)
-    v = pool_ref[:, v_off:v_off + hkd].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    kpos = j * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < kv_len, s, NEG_INF)          # (hk*g, chunk)
+    @pl.when(j * chunk < kv_len)                      # a page in use
+    def _page():
+        # (chunk, hk*dh): the whole window block, or the layer's lane-
+        # aligned columns of a whole-row block
+        k = k_ref[:, k_lo:k_lo + hkd].astype(jnp.float32)
+        v = v_ref[:, v_lo:v_lo + hkd].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+        kpos = j * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < kv_len, s, NEG_INF)      # (hk*g, chunk)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.where(m_new <= NEG_INF, 0.0, jnp.exp(s - m_new))
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[...] = m_new
-    # (hk*g, hk*dh): row (h, g) is meaningful only in head h's columns
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(m_new <= NEG_INF, 0.0, jnp.exp(s - m_new))
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_new
+        # (hk*g, hk*dh): row (h, g) is meaningful only in head h's columns
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())))
 
     @pl.when(j == num_pages - 1)
     def _finish():
         o_ref[0] = (acc_scr[...]
                     / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_block_width(hkd: int, k_off: int, v_off: int,
+                      token_row: int) -> int:
+    """Width of the pool blocks the paged kernel DMAs: the layer's own
+    ``hkd``-wide K and V column windows where both are whole lane-aligned
+    blocks of the row (every served configuration: hk*dh is a multiple of
+    128 and the stacks' K/V offsets multiples of hk*dh), else the whole
+    token row, DMA'd once per operand and sliced in VMEM (only shapes no
+    configuration serves)."""
+    if hkd % 128 == 0 and k_off % hkd == 0 and v_off % hkd == 0:
+        return hkd
+    return token_row
 
 
 def paged_decode_attention_grouped(q, cur_k, cur_v, pool_rows, page_rows,
@@ -156,22 +177,33 @@ def paged_decode_attention_grouped(q, cur_k, cur_v, pool_rows, page_rows,
     Attends over [pool tokens 0..lengths[b]) ; current token] per request.
     The kernel works on 2-D tiles only: the query enters block-diagonal
     (B, Hkv*G, Hkv*Dh), K and V are the page's (chunk, Hkv*Dh) column
-    slices, and each head's output is read back off the diagonal here.
+    windows (``paged_block_width``), and each head's output is read back
+    off the diagonal here.  Only pages holding tokens below ``lengths[b]``
+    are read.
     """
     b, hk, g, dh = q.shape
     hkd = hk * dh
     num_pages = page_rows.shape[1]
-    token_row = pool_rows.shape[1]
+    w = paged_block_width(hkd, k_off, v_off, pool_rows.shape[1])
     own_head = jnp.eye(hk, dtype=bool)[:, None, :, None]      # (hk,1,hk,1)
     q_bd = jnp.where(own_head, q[:, :, :, None, :],
                      jnp.zeros((), q.dtype)).reshape(b, hk * g, hkd)
     scalars = jnp.concatenate([
         jnp.asarray(lengths, jnp.int32).reshape(-1),
         jnp.asarray(page_rows, jnp.int32).reshape(-1)])
+
+    def pool_block(col):
+        # the page table drives the pool DMA: page j of request bb, clamped
+        # to its last page in use so later steps re-name the fetched block
+        def index(bb, j, s):
+            last = jax.lax.div(jnp.maximum(s[bb] - 1, 0), chunk)
+            return s[b + bb * num_pages + jnp.minimum(j, last)], col
+        return pl.BlockSpec((chunk, w), index)
+
     grid = (b, num_pages)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, chunk=chunk,
-                          k_off=k_off, v_off=v_off, hkd=hkd,
+                          k_lo=k_off % w, v_lo=v_off % w, hkd=hkd,
                           num_pages=num_pages),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -180,9 +212,8 @@ def paged_decode_attention_grouped(q, cur_k, cur_v, pool_rows, page_rows,
                 pl.BlockSpec((1, hk * g, hkd), lambda bb, j, _s: (bb, 0, 0)),
                 pl.BlockSpec((1, 1, hkd), lambda bb, j, _s: (bb, 0, 0)),
                 pl.BlockSpec((1, 1, hkd), lambda bb, j, _s: (bb, 0, 0)),
-                # the page table drives the pool DMA: page j of request bb
-                pl.BlockSpec((chunk, token_row),
-                             lambda bb, j, s: (s[b + bb * num_pages + j], 0)),
+                pool_block(k_off // w),
+                pool_block(v_off // w),
             ],
             out_specs=pl.BlockSpec((1, hk * g, hkd),
                                    lambda bb, j, _s: (bb, 0, 0)),
@@ -194,7 +225,7 @@ def paged_decode_attention_grouped(q, cur_k, cur_v, pool_rows, page_rows,
         out_shape=jax.ShapeDtypeStruct((b, hk * g, hkd), q.dtype),
         interpret=interpret,
     )(scalars, q_bd, cur_k.reshape(b, 1, hkd), cur_v.reshape(b, 1, hkd),
-      pool_rows)
+      pool_rows, pool_rows)
     # row (h, g) of head h's column block: the diagonal of (hk, hk)
     out = jnp.diagonal(out.reshape(b, hk, g, hk, dh), axis1=1, axis2=3)
     return out.transpose(0, 3, 1, 2)                  # (b, hk, g, dh)

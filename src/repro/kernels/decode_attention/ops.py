@@ -3,7 +3,8 @@
 ``decode_attention``: (B, 1, H, Dh) query + (B, T, Hkv, Dh) caches.
 ``paged_decode_attention``: (B, 1, H, Dh) query + the LeaseEngine pool's
 (n_rows, token_row) view + per-request page tables / lengths + the current
-token's fresh (k, v) -- KV never leaves its pool pages.
+token's fresh (k, v) -- KV never leaves its pool pages, and each call reads
+only its layer's K and V columns of the pages in use.
 """
 from __future__ import annotations
 
@@ -49,8 +50,8 @@ def paged_decode_attention(q, cur_k, cur_v, pool_rows, page_rows, lengths,
     cache stack's segment (a stack's rows pack every layer's K then V
     contiguously) and ``pool_off`` is the stack's segment offset inside the
     interleaved multi-pool token row (0 for single-stack families) -- the
-    kernel slices the page row at ``pool_off + k_off`` / ``pool_off +
-    v_off``, so one page DMA serves every stack living in the row.
+    kernel DMAs the (chunk, Hkv*Dh) windows at ``pool_off + k_off`` and
+    ``pool_off + v_off`` of each page in use, not the whole row.
     """
     b, one, h, dh = q.shape
     g = h // hkv

@@ -334,20 +334,23 @@ def _gather_kernel(idx_ref, pool_hbm, out_ref, buf, sem, *, tile: int,
 
 
 def _scatter_kernel(idx_ref, rows_ref, pool_hbm, out_hbm, buf, sem, *,
-                    tile: int, col_lo: int, width: int):
+                    tile: int, col_lo: int, width: int, n_rows: int):
     del pool_hbm                         # aliased to out_hbm
     i = pl.program_id(0)
     row = idx_ref[i]
-    window = _window(out_hbm, row, tile, col_lo, width)
-    load = pltpu.make_async_copy(window, buf, sem)
-    load.start()
-    load.wait()
-    new = _pick_row(rows_ref[...], i % tile).astype(buf.dtype)
-    hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == row % tile
-    buf[...] = jnp.where(hit, new, buf[...])
-    store = pltpu.make_async_copy(buf, window, sem)
-    store.start()
-    store.wait()
+
+    @pl.when(row < n_rows)               # an id past the pool: no write
+    def _write():
+        window = _window(out_hbm, row, tile, col_lo, width)
+        load = pltpu.make_async_copy(window, buf, sem)
+        load.start()
+        load.wait()
+        new = _pick_row(rows_ref[...], i % tile).astype(buf.dtype)
+        hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == row % tile
+        buf[...] = jnp.where(hit, new, buf[...])
+        store = pltpu.make_async_copy(buf, window, sem)
+        store.start()
+        store.wait()
 
 
 def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
@@ -365,7 +368,8 @@ def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
     without a host round trip, and a per-stack append touches only that
     stack's segment of the interleaved token row.  Each step waits for its
     write before the next step reads, so rows listed twice keep the last
-    write, and rows that share a tile all land.
+    write, and rows that share a tile all land.  An id at or past the
+    pool's end is skipped, as XLA's scatter drops it.
     """
     n, width = rows.shape
     assert col_lo % LANES == 0 and width % LANES == 0, (col_lo, width)
@@ -373,7 +377,7 @@ def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
     rows = jnp.pad(rows, ((0, (-n) % tile), (0, 0)))
     return pl.pallas_call(
         functools.partial(_scatter_kernel, tile=tile, col_lo=col_lo,
-                          width=width),
+                          width=width, n_rows=pool.shape[0]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),

@@ -227,8 +227,9 @@ def decode_step_paged(cfg: ArchConfig, p: Params, pool_rows, page_rows,
     lane-padded row per token, all layers packed); ``page_rows``: (B, P)
     int32 per-request page tables (entries past a request's pages clamped
     to a valid id -- they are masked by ``lengths``); ``lengths``: (B,)
-    int32 tokens already in pages (== the decode position); ``tokens``:
-    (B, 1) int32.  Returns (new_pool_rows, logits (B, 1, V)): every
+    int32 tokens already in pages (== the decode position), 0 for an empty
+    slot (every request holds its prompt, so a real row is never 0): its
+    token is computed and not appended; ``tokens``: (B, 1) int32.  Returns (new_pool_rows, logits (B, 1, V)): every
     layer's fresh KV for the new token is accumulated into ONE token row
     and scattered into its page by the ``tardis_lease`` append kernel --
     no host round trip, no dense per-request cache anywhere.
@@ -282,6 +283,8 @@ def decode_step_paged(cfg: ArchConfig, p: Params, pool_rows, page_rows,
     with jax.named_scope("kv_append"):
         flat_idx = (page_rows[jnp.arange(b), lengths // chunk] * chunk
                     + lengths % chunk)
+        # an empty slot's row goes past the pool's end: the scatter skips it
+        flat_idx = jnp.where(lengths > 0, flat_idx, pool_rows.shape[0])
         pool_rows = scatter_rows(pool_rows, flat_idx, row_buf,
                                  interpret=interpret)
     with jax.named_scope("head"):

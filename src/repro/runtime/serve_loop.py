@@ -136,6 +136,7 @@ class Stream:
     reloc_pages: List[int]           # eviction-relocated private copies
     length: int                      # tokens currently in pages
     emitted: List[int]
+    reserved_pages: int              # pages its table names: prompt + max_new
 
     @property
     def finished(self) -> bool:
@@ -325,6 +326,11 @@ class ServingCluster:
             "prefix_prefill_tokens_skipped": 0, "prefix_flops_saved": 0,
             "decode_renewals": 0, "decode_local_hits": 0,
             "decode_renewals_skipped": 0, "decode_block_reads": 0,
+            # pages the decode ticks' page tables reserve (prompt plus
+            # max_new), beside decode_block_reads' pages in use (the
+            # append's page included): 1 - reads / this is about the share
+            # of page reads the paged kernel skips past each stream's length
+            "decode_pages_reserved": 0,
             "pinned_relocations": 0, "paged_mid_batch_admissions": 0,
             "paged_admission_deferrals": 0, "pool_page_peak": 0,
             "xhost_pages_fetched": 0, "xhost_pages_published": 0,
@@ -959,7 +965,7 @@ class ServingCluster:
         t0 = int(np.argmax(np.asarray(logits[0, -1])))
         stream = Stream(req=req, page_row=page_row, own_pages=own,
                         shared_bids=list(bids[:covered]), reloc_pages=[],
-                        length=plen, emitted=[t0])
+                        length=plen, emitted=[t0], reserved_pages=total_pages)
         in_use = self.n_decode_pages - eng.free_page_count()
         ps["pool_page_peak"] = max(ps["pool_page_peak"], in_use)
         if self.trace is not None:
@@ -1144,9 +1150,20 @@ class ServingCluster:
         with self._span("renew"):
             self._renew_decode_leases(rep, act)
         bt = self.prefix_block_tokens
-        page_rows = np.stack([s.page_row for s in act])
-        lengths = np.asarray([s.length for s in act], np.int32)
-        tokens = np.asarray([[s.emitted[-1]] for s in act], np.int32)
+        n = len(act)
+        # one decode shape: the batch is padded to max_batch with empty
+        # slots (length 0: attended by no page, appended nowhere), so a
+        # tick whose batch shrank runs the program already compiled.  moe
+        # decodes its live batch: expert capacity follows the batch size
+        # (moe._capacity), and empty slots would change which tokens drop
+        rows = n if self.cfg.family == "moe" else max(n, rep.max_batch)
+        page_rows = np.zeros((rows, self.max_pages), np.int32)
+        lengths = np.zeros(rows, np.int32)
+        tokens = np.zeros((rows, 1), np.int32)
+        for i, s in enumerate(act):
+            page_rows[i] = s.page_row
+            lengths[i] = s.length
+            tokens[i] = s.emitted[-1]
         params = rep.params()             # weight lease check per tick
         with warnings.catch_warnings():
             # CPU XLA can't honor the pool donation; the TPU path does
@@ -1154,17 +1171,19 @@ class ServingCluster:
             pool, logits = self._decode_paged_fn(
                 params, eng.kv_rows_view(), jnp.asarray(page_rows),
                 jnp.asarray(lengths), jnp.asarray(tokens))
-        eng.set_kv_rows(pool, tokens_appended=len(act))
+        eng.set_kv_rows(pool, tokens_appended=n)
         self.prefix_stats["decode_block_reads"] += int(
-            sum(-(-(int(n) + 1) // bt) for n in lengths))
+            np.sum(-(-(lengths[:n] + 1) // bt)))
+        self.prefix_stats["decode_pages_reserved"] += sum(
+            s.reserved_pages for s in act)
         with self._span("decode_wait"):
-            nxt = np.asarray(jnp.argmax(logits[:, -1], -1))
+            nxt = np.asarray(jnp.argmax(logits[:, -1], -1))[:n]
         if self.trace is not None:
             self.trace.append({
                 "ev": "tick", "tick": tick, "rep": rep.name,
-                "rids": [s.req.rid for s in act],
-                "lengths": lengths.copy(), "tokens": tokens.copy(),
-                "logits": np.asarray(logits).copy()})
+                "rids": [s.req.rid for s in act], "rows": rows,
+                "lengths": lengths[:n].copy(), "tokens": tokens[:n].copy(),
+                "logits": np.asarray(logits)[:n].copy()})
         done = []
         for s, t in zip(act, nxt):
             s.length += 1

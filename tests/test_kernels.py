@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.registry import ARCHS
+from repro.kernels.decode_attention.kernel import paged_block_width
 from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
@@ -25,6 +27,7 @@ from repro.kernels.tardis_lease.ref import (append_rows_ref,
                                             masked_lease_check_many_ref,
                                             masked_lease_check_ref,
                                             write_advance_ref)
+from repro.models.decoding import PAGED_FAMILIES, pool_layout
 
 KEY = jax.random.PRNGKey(0)
 TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
@@ -130,35 +133,92 @@ def test_tardis_masked_ops(n, pts):
     assert int(t1) == int(t2)
 
 
-@pytest.mark.parametrize("b,h,hk,d,chunk,nb,p", [
+# toy shapes: where hk*d is no multiple of 128 (32, 16) each page's whole
+# token row is DMA'd and the layer's columns sliced in VMEM
+_TOY_SHAPES = [
     (3, 6, 2, 16, 4, 8, 3),
     (2, 4, 4, 32, 8, 16, 4),     # MHA-style, bigger pages
     (1, 8, 1, 16, 16, 4, 2),     # MQA
+]
+
+
+@pytest.mark.parametrize("layers,layer,b,h,hk,d,chunk,nb,p", [
+    (layers, layer) + shape
+    for layers, layer in [(2, 0), (2, 1), (1, 0)] for shape in _TOY_SHAPES
+] + [
+    # lane-aligned column windows: a middle layer, K and V off column 0
+    (3, 1, 3, 4, 1, 128, 16, 12, 4),
+    (4, 2, 2, 8, 2, 128, 16, 10, 5),
+    # two stacks (kimi's layout cut down): layer 2 of the second stack,
+    # whose segment starts at the lane-padded end of the first
+    ((1, 3), 2, 2, 8, 1, 128, 16, 10, 5),
 ])
-@pytest.mark.parametrize("layers,layer", [(2, 0), (2, 1), (1, 0)])
 def test_paged_decode_attention_kernel(b, h, hk, d, chunk, nb, p, layers,
                                        layer):
     """Paged flash-decode (page tables drive the K/V DMA) vs the
-    gather-then-reference oracle, across ragged per-request lengths."""
+    gather-then-reference oracle, across ragged per-request lengths.
+
+    Where the kernel reads column windows, the pool it is given holds NaN
+    in every other layer's (and stack's) columns and in every reserved page
+    past a request's length; the oracle reads the pool unpoisoned.  A read
+    of a foreign column or any use of an unwritten page turns the output
+    NaN."""
     rng = np.random.default_rng(b * 100 + h)
-    te = 2 * layers * hk * d
+    stacks = layers if isinstance(layers, tuple) else (layers,)
+    hkd = hk * d
+    pool_off = sum(-(-2 * n * hkd // 128) * 128 for n in stacks[:-1])
+    te = pool_off + 2 * stacks[-1] * hkd
     token_row = -(-te // 128) * 128
-    pool = jnp.asarray(rng.standard_normal((nb * chunk, token_row)),
-                       jnp.float32)
-    page_rows = jnp.asarray(rng.integers(0, nb, (b, p)), jnp.int32)
-    lengths = jnp.asarray(rng.integers(0, p * chunk, b), jnp.int32)
+    pool = rng.standard_normal((nb * chunk, token_row)).astype(np.float32)
+    k_off, v_off = layer * hkd, (stacks[-1] + layer) * hkd
+    page_rows = rng.integers(0, nb, (b, p))
+    lengths = rng.integers(0, p * chunk, b)
+    windows = paged_block_width(hkd, pool_off + k_off, pool_off + v_off,
+                                token_row) == hkd
+    assert windows == (hkd % 128 == 0)
+    if windows:
+        # distinct pages per request, one length on a page boundary
+        page_rows = rng.permutation(nb)[:b * p].reshape(b, p)
+        lengths[0] = chunk
+        poisoned = np.full_like(pool, np.nan)
+        for lo in (pool_off + k_off, pool_off + v_off):
+            poisoned[:, lo:lo + hkd] = pool[:, lo:lo + hkd]
+        for bb in range(b):
+            for pg in page_rows[bb, -(-lengths[bb] // chunk):]:
+                poisoned[pg * chunk:(pg + 1) * chunk] = np.nan
+    else:
+        poisoned = pool
+    page_rows = jnp.asarray(page_rows, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
     ck = jnp.asarray(rng.standard_normal((b, 1, hk, d)), jnp.float32)
     cv = jnp.asarray(rng.standard_normal((b, 1, hk, d)), jnp.float32)
-    k_off, v_off = layer * hk * d, (layers + layer) * hk * d
-    out = paged_decode_attention(q, ck, cv, pool, page_rows, lengths,
-                                 chunk=chunk, k_off=k_off, v_off=v_off,
-                                 hkv=hk, interpret=True)
-    ref = paged_decode_attention_ref(q, ck, cv, pool, page_rows, lengths,
-                                     chunk=chunk, k_off=k_off, v_off=v_off,
-                                     hkv=hk)
+    out = paged_decode_attention(q, ck, cv, jnp.asarray(poisoned), page_rows,
+                                 lengths, chunk=chunk, k_off=k_off,
+                                 v_off=v_off, hkv=hk, pool_off=pool_off,
+                                 interpret=True)
+    ref = paged_decode_attention_ref(q, ck, cv, jnp.asarray(pool), page_rows,
+                                     lengths, chunk=chunk,
+                                     k_off=pool_off + k_off,
+                                     v_off=pool_off + v_off, hkv=hk)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", sorted(
+    n for n, c in ARCHS.items() if c.family in PAGED_FAMILIES))
+def test_paged_configs_take_column_windows(arch):
+    """Every served paged configuration's layers, in every cache stack,
+    read their own K/V column windows, never the whole token row."""
+    cfg = ARCHS[arch]
+    hkd = cfg.n_kv_heads * cfg.head_dim()
+    stacks = pool_layout(cfg)
+    token_row = stacks[-1].offset + -(-stacks[-1].token_elems // 128) * 128
+    for s in stacks:
+        for li in range(s.n_layers):
+            assert paged_block_width(
+                hkd, s.offset + li * hkd, s.offset + (s.n_layers + li) * hkd,
+                token_row) == hkd, (arch, s.pool, li)
 
 
 @pytest.mark.parametrize("n,w,rows_w", [(8, 256, 256), (16, 512, 300)])
@@ -171,6 +231,20 @@ def test_append_rows_scatter_kernel(n, w, rows_w):
     rows = jnp.asarray(rng.standard_normal((4, rows_w)), jnp.float32)
     ref = np.asarray(append_rows_ref(pool, idx, rows))
     out = append_rows(pool, idx, rows, interpret=True)   # donates pool
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+@pytest.mark.parametrize("col_lo,w", [(0, 256), (128, 128)])
+def test_scatter_rows_skips_ids_past_pool(col_lo, w):
+    """An id at or past the pool's end writes nothing (an empty decode
+    slot's row), as XLA's own scatter drops it; the other rows land."""
+    from repro.kernels.tardis_lease.kernel import scatter_rows
+    rng = np.random.default_rng(w)
+    pool = jnp.asarray(rng.standard_normal((64, 256)), jnp.float32)
+    idx = jnp.asarray([5, 64, 0, 64, 63], jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((5, w)), jnp.float32)
+    ref = np.asarray(pool.at[idx, col_lo:col_lo + w].set(rows))
+    out = scatter_rows(pool, idx, rows, col_lo=col_lo, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), ref)
 
 

@@ -80,7 +80,9 @@ def _replay_dense_shadow(arch, cluster, trace):
     """Re-run the recorded schedule on dense per-request caches seeded from
     the same pool bits and assert bitwise-equal logits every tick.  Each
     cache stack (moe: dk/dv and k/v) is sliced out of its own pool segment
-    at the ``pool_layout`` offset."""
+    at the ``pool_layout`` offset.  The dense batch takes the paged step's
+    rows, its empty slots included (zero caches, token 0, length 0), so
+    both run their matmuls at one shape."""
     cfg, params = _arch(arch)
     stacks = pool_layout(cfg)
     names = [k for s in stacks for k in s.cache_keys]
@@ -106,13 +108,16 @@ def _replay_dense_shadow(arch, cluster, trace):
                 c[s.cache_keys[1]] = v
             caches[ev["rid"]] = c
         else:
-            cache = {n: jnp.asarray(np.stack(
-                [caches[r][n] for r in ev["rids"]], axis=1))
-                for n in names}
-            cache2, logits = dec(params, cache, jnp.asarray(ev["tokens"]),
-                                 jnp.asarray(ev["lengths"], jnp.int32))
+            live = len(ev["rids"])
+            pad = (0, ev["rows"] - live)
+            cache = {n: jnp.asarray(np.pad(
+                np.stack([caches[r][n] for r in ev["rids"]], axis=1),
+                [(0, 0), pad, (0, 0), (0, 0), (0, 0)])) for n in names}
+            cache2, logits = dec(
+                params, cache, jnp.asarray(np.pad(ev["tokens"], [pad, (0, 0)])),
+                jnp.asarray(np.pad(ev["lengths"], pad), jnp.int32))
             np.testing.assert_array_equal(
-                np.asarray(logits), ev["logits"],
+                np.asarray(logits)[:live], ev["logits"],
                 err_msg=f"paged decode diverged at tick {ev['tick']} "
                         f"(arch {arch}, rids {ev['rids']})")
             for i, r in enumerate(ev["rids"]):
@@ -248,6 +253,47 @@ def test_decode_holds_leases_and_ledgers_renewals():
     assert (cluster.prefix_engine.stats.read_ops - reads0
             <= 1 + rep["decode_renewals"])
     _replay_dense_shadow("dense", cluster, cluster.trace)
+
+
+@pytest.mark.parametrize("arch", sorted(ARCH_BASES))
+def test_decode_runs_one_shape_as_batch_shrinks(arch):
+    """Streams finish one by one, and every decode tick still runs the one
+    program compiled at ``max_batch`` rows: the empty slots append nothing
+    (the dense shadow stays bit-exact) and emit nothing.  moe decodes its
+    live batch, one shape for each size it meets."""
+    cfg, _ = _arch(arch)
+    rng = np.random.default_rng(3)
+    cluster = _cluster(arch, n_replicas=1, max_batch=4)
+    reqs = [Request(i, rng.integers(1, cfg.vocab, 5).astype(np.int32),
+                    max_new=2 + 2 * i) for i in range(4)]
+    done, rep = cluster.run(reqs)
+    assert [len(r.output) for r in done] == [r.max_new for r in reqs]
+    sizes = {len(ev["rids"]) for ev in cluster.trace if ev["ev"] == "tick"}
+    assert sizes == {1, 2, 3, 4}
+    shapes = cluster._decode_paged_fn._cache_size()
+    assert shapes == (len(sizes) if cfg.family == "moe" else 1)
+    _replay_dense_shadow(arch, cluster, cluster.trace)
+    _check_pool_drained(cluster)
+
+
+def test_decode_pages_reserved_counts_page_tables():
+    """``decode_pages_reserved`` sums, over the decode ticks, each stream's
+    reserved pages (prompt plus ``max_new``): never fewer than the pages in
+    use (``decode_block_reads``), and as many when every reserved page
+    holds a token from the first tick on."""
+    cfg, _ = _arch("dense")
+    rng = np.random.default_rng(9)
+    cluster = _cluster(n_replicas=1)
+    bt = cluster.prefix_block_tokens
+    # long outputs reserve pages that the early ticks have not written
+    _, rep = cluster.run(_reqs(rng, cfg, 4, max_new_hi=3 * bt))
+    assert rep["decode_pages_reserved"] > rep["decode_block_reads"] > 0
+    # prompt plus output within one page: that page is in use every tick
+    cluster = _cluster(n_replicas=1)
+    _, rep = cluster.run([
+        Request(i, rng.integers(1, cfg.vocab, 1).astype(np.int32),
+                max_new=bt - 1) for i in range(3)])
+    assert rep["decode_pages_reserved"] == rep["decode_block_reads"] > 0
 
 
 def test_moe_dual_stack_pool_layout_matches_engine():

@@ -7,7 +7,9 @@ each kernel of the paged serving path against shapes only, at the widths
 tinyllama-1.1b serves with: the four ``tardis_lease`` table kernels at
 ``ServingCluster``'s default table and at the serve CLI's, the page gather
 and the decode append at the full token row, the paged flash-decode kernel
-at the model's head layout, and one ``decode_step_paged`` cut to 2 layers.
+at the model's head layout (and at the serving benchmark's glm4-9b and
+mistral-nemo-12b pages, and in both of kimi-k2's cache stacks), and one
+``decode_step_paged`` cut to 2 layers.
 Nothing runs, so nothing here says anything about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -113,19 +115,47 @@ def test_pool_row_kernels_compile(one_chip, op, window):
             p, i, r, col_lo=col_lo), pool, _shape(one_chip, (BATCH,)), rows)
 
 
-@pytest.mark.parametrize("layer", [0, TINYLLAMA.n_layers - 1])
-def test_paged_decode_attention_compiles(one_chip, layer):
-    cfg = TINYLLAMA
+KIMI = get_arch("kimi-k2-1t-a32b")
+
+# (config, cache stack, layer, page tokens, pool blocks, decode slots,
+# page-table length): tinyllama at the serve CLI's pages; the serving
+# benchmark's glm4-9b (20 layers) and mistral-nemo-12b (10 layers) at their
+# 256-token pages, pools and batches; kimi-k2's 896-wide windows in its
+# dense stack and in its moe stack, whose segment starts at 1792
+PAGED_CELLS = [
+    pytest.param(TINYLLAMA, 0, 0, CHUNK, TABLE_BLOCKS[0], BATCH, PAGES,
+                 id="0"),
+    pytest.param(TINYLLAMA, 0, TINYLLAMA.n_layers - 1, CHUNK,
+                 TABLE_BLOCKS[0], BATCH, PAGES, id="21"),
+    pytest.param(dataclasses.replace(get_arch("glm4-9b"), n_layers=20), 0,
+                 9, 256, 296, 32, 9, id="glm4-9b"),
+    pytest.param(dataclasses.replace(get_arch("mistral-nemo-12b"),
+                                     n_layers=10), 0, 4, 256, 544, 16, 19,
+                 id="mistral-nemo-12b"),
+    pytest.param(KIMI, 0, 0, CHUNK, 512, BATCH, PAGES, id="kimi-dense"),
+    pytest.param(KIMI, 1, 30, CHUNK, 512, BATCH, PAGES, id="kimi-moe"),
+]
+
+
+@pytest.mark.parametrize("cfg,stack,layer,chunk,n_blocks,batch,pages",
+                         PAGED_CELLS)
+def test_paged_decode_attention_compiles(one_chip, cfg, stack, layer, chunk,
+                                         n_blocks, batch, pages):
+    """One layer's paged flash-decode at the default scoped-VMEM limit (no
+    libtpu flag): its blocks are the layer's (chunk, hk*dh) K and V
+    windows, not the (chunk, token_row) page row."""
     hk, dh = cfg.n_kv_heads, cfg.head_dim()
-    pool = _shape(one_chip, (TABLE_BLOCKS[0] * CHUNK, _token_row(cfg)),
+    spec = pool_layout(cfg)[stack]
+    pool = _shape(one_chip, (n_blocks * chunk, _token_row(cfg)),
                   jnp.bfloat16)
-    q = _shape(one_chip, (BATCH, 1, cfg.n_heads, dh), jnp.float32)
-    cur = _shape(one_chip, (BATCH, 1, hk, dh), jnp.bfloat16)
+    q = _shape(one_chip, (batch, 1, cfg.n_heads, dh), jnp.float32)
+    cur = _shape(one_chip, (batch, 1, hk, dh), jnp.bfloat16)
     _compile(lambda q_, k_, v_, p, pr, ln: paged_decode_attention(
-        q_, k_, v_, p, pr, ln, chunk=CHUNK, k_off=layer * hk * dh,
-        v_off=(cfg.n_layers + layer) * hk * dh, hkv=hk),
-        q, cur, cur, pool, _shape(one_chip, (BATCH, PAGES)),
-        _shape(one_chip, (BATCH,)))
+        q_, k_, v_, p, pr, ln, chunk=chunk, k_off=layer * hk * dh,
+        v_off=(spec.n_layers + layer) * hk * dh, hkv=hk,
+        pool_off=spec.offset),
+        q, cur, cur, pool, _shape(one_chip, (batch, pages)),
+        _shape(one_chip, (batch,)))
 
 
 def test_decode_step_paged_compiles(one_chip):
