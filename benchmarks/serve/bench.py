@@ -5,7 +5,18 @@
         --seconds <s> --trace <0|1>
 
 A cell names a configuration (``configs/<config>.json``: published sizes,
-what was cut, the page pool) and a traffic mix (``traffic/<mix>.json``).
+what was cut, the page pool, and the ``model`` it is) and a traffic mix
+(``traffic/<mix>.json``).  The configuration's ``model`` names a package,
+``models/<model>/``, that gives the harness everything that depends on the
+model: its sizes, the program's family and fields, the weights, the plain
+reference and the work counts the rooflines read (the contract is in
+``models/dense/__init__.py``).  So a new model is new files only:
+``models/<model>/``, ``configs/<config>.json``, ``traffic/<mix>.json`` and
+``limits/<workload>.json``, with entries in ``BENCHMARK.json``; no file
+already here changes.  A model whose vocabulary is cut to a chip's share
+states the cut vocabulary in its sizes: traffic and the warm-up draw token
+ids from ``sizes.vocab``.
+
 The run, in one process:
 
 1. set-up: weights made on the device from the seed (bf16), one
@@ -21,10 +32,10 @@ The run, in one process:
    reduced to the per-layer metrics (``metrics/<name>.py``);
 4. the check: once the window has closed and the program's device state is
    freed, a sample of finished requests drawn from the seed, with the
-   longest among them, is run through the float32 reference
-   (``reference.py``); the widest gap by which a served token's logit lies
-   below the reference's best, in units of that position's logit RMS, must
-   stay under the cell's limit (``limits/<workload>.json``).  With
+   longest among them, is run through the float32 reference (the
+   model's ``logits_at``); the widest gap by which a served token's logit
+   lies below the reference's best, in units of that position's logit
+   RMS, must stay under the cell's limit (``limits/<workload>.json``).  With
    ``--control 1`` the tokens the float8 control puts first are judged in
    place of the served ones, through the same limit: such a run has to
    come out not correct.
@@ -43,12 +54,13 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import importlib  # noqa: E402
+import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Any, Dict, List, Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -75,23 +87,6 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    """The decoder's sizes as the configuration file states them."""
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    d_head: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    norm_eps: float
-
-    def head_dim(self) -> int:
-        return self.d_head
-
-
 @dataclasses.dataclass
 class Cell:
     name: str
@@ -102,7 +97,8 @@ class Cell:
     limit: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
-    sizes: Sizes
+    model: Any                # the package models/<model>/
+    sizes: Any                # model.sizes(conf)
 
     @property
     def serving(self) -> Dict:
@@ -113,24 +109,42 @@ def _applies(metric: Dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
+def load_model(bench_dir: str, name: str):
+    """The package ``models/<name>/`` of the benchmark's directory, as
+    ``models.<name>``."""
+    full = f"models.{name}"
+    if full not in sys.modules:
+        path = os.path.join(bench_dir, "models", name)
+        spec = importlib.util.spec_from_file_location(
+            full, os.path.join(path, "__init__.py"),
+            submodule_search_locations=[path])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[full] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[full]
+
+
 def load_cell(workload: str, root: str = ROOT) -> Cell:
+    """The cell as ``<root>/BENCHMARK.json`` defines it; traffic, limits
+    and models are read from the benchmark's directory (its first path)."""
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    here = os.path.join(root, bench["paths"][0])
     w = next((x for x in bench["workloads"] if x["name"] == workload), None)
     if w is None:
         raise SystemExit(f"bench: no workload {workload!r}")
     c = next(x for x in bench["configs"] if x["name"] == w["config"])
     with open(os.path.join(root, c["file"])) as f:
         conf = json.load(f)
-    mix = traffic.load_mix(os.path.join(HERE, "traffic",
+    mix = traffic.load_mix(os.path.join(here, "traffic",
                                         w["traffic"] + ".json"))
-    with open(os.path.join(HERE, "limits", workload + ".json")) as f:
+    with open(os.path.join(here, "limits", workload + ".json")) as f:
         limit = json.load(f)
-    sizes = Sizes(**{k: conf[v] for k, v in conf["arch_keys"].items()})
+    model = load_model(here, conf["model"])
     return Cell(workload, int(w["chips"]), c["name"], conf, mix, limit,
                 [m for m in bench["end_to_end"] if _applies(m, workload)],
                 [m for m in bench["per_layer"] if _applies(m, workload)],
-                sizes)
+                model, model.sizes(conf))
 
 
 class Compiles:
@@ -158,7 +172,8 @@ class Run:
     ticks: List[Dict]
     counters_open: Dict[str, int]
     counters_close: Dict[str, int]
-    sizes: Sizes
+    model: Any
+    sizes: Any
     peaks: Dict
     trace: Optional[Dict]
 
@@ -192,6 +207,7 @@ def warm(cluster, cell: Cell, comp: Compiles) -> int:
     s = cell.sizes
     mix = cell.mix
     prefixes = traffic.shared_prompts(mix, s.vocab)
+    head = traffic.unique_head_tokens(mix)
     lengths = traffic.support_points(mix["prompt_tokens"])
     serving = cell.serving
     rng = np.random.default_rng(WARM_SEED)
@@ -200,6 +216,9 @@ def warm(cluster, cell: Cell, comp: Compiles) -> int:
     rid = [-1]
 
     def submit(prompt, max_new):
+        if head:    # a head of its own, as every request of the mix has
+            prompt = np.concatenate(
+                [rng.integers(1, s.vocab, head).astype(np.int32), prompt])
         driver.submit(queues, rid[0], prompt, max_new)
         rid[0] -= 1
 
@@ -340,7 +359,8 @@ def end_to_end(rec) -> Dict[str, float]:
 
 def check_length(cell: Cell) -> int:
     sp = cell.mix.get("shared_prefix") or {}
-    longest = (sp.get("tokens", 0) + cell.mix["prompt_tokens"]["max"]
+    longest = (sp.get("tokens", 0) + traffic.unique_head_tokens(cell.mix)
+               + cell.mix["prompt_tokens"]["max"]
                + cell.mix["output_tokens"]["max"])
     return -(-longest // CHECK_BLOCK) * CHECK_BLOCK
 
@@ -392,7 +412,6 @@ def gaps(cell: Cell, seed: int, picked: List, control: bool) -> Dict:
     kept beside them."""
     import jax
     import jax.numpy as jnp
-    import reference
     n_seq = cell.mix["check"]["max_requests"]
     length = check_length(cell)
     n_rows = check_rows(cell)
@@ -407,10 +426,11 @@ def gaps(cell: Cell, seed: int, picked: List, control: bool) -> Dict:
     flat = np.pad(np.asarray(flat, np.int64), (0, n_rows - n))
     toks = np.pad(np.asarray(toks, np.int32), (0, n_rows - n))
     t = time.perf_counter()
-    ref = reference.logits_at(cell.sizes, seed, seqs, flat, length, "f32")
+    ref = cell.model.logits_at(cell.sizes, seed, seqs, flat, length, "f32")
     stat = jax.jit(_gap_stats)
     served, _ = (np.asarray(x)[:n] for x in stat(ref, jnp.asarray(toks)))
-    judged = (reference.logits_at(cell.sizes, seed, seqs, flat, length, "fp8")
+    judged = (cell.model.logits_at(cell.sizes, seed, seqs, flat, length,
+                                   "fp8")
               if control else jnp.asarray(toks))
     g, top1 = (np.asarray(x)[:n] for x in stat(ref, judged))
     log(f"reference: {time.perf_counter() - t:.3f} s for {len(picked)} "
@@ -428,7 +448,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     """Everything but the look for a chip; returns the result object."""
     driver.import_program()
     import jax
-    import weights
     comp = Compiles(jax)
     cache_dir = driver.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -438,10 +457,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if peaks is None and dev.platform == "tpu":
         raise SystemExit(f"bench: no peaks for device {dev.device_kind!r}")
     s = cell.sizes
-    params = weights.make_params(seed, s)
+    params = cell.model.make_params(seed, s)
     weight_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
-    arch = driver.arch_config(cell.config_name,
-                              dataclasses.asdict(s))
+    arch = driver.arch_config(cell.config_name, cell.model.FAMILY,
+                              cell.model.arch_fields(s))
     cluster = driver.build_cluster(arch, params, cell.serving)
     del params
     page_bytes = driver.page_bytes(cluster)
@@ -482,8 +501,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         if obj is not None and driver.request_done(obj):
             finished.append((r.prompt, driver.request_output(obj)))
     log(f"finished in window: {len(finished)} of {len(reqs)}")
-    run = Run(rec["ticks"], rec["counters_open"], rec["counters_close"], s,
-              peaks or {}, rec.get("trace"))
+    run = Run(rec["ticks"], rec["counters_open"], rec["counters_close"],
+              cell.model, s, peaks or {}, rec.get("trace"))
     metrics: Dict[str, Dict] = {}
     breakdown = None
     if trace:

@@ -32,9 +32,9 @@ def enable_compile_cache() -> str:
     return enable()
 
 
-def arch_config(name: str, fields: Dict):
+def arch_config(name: str, family: str, fields: Dict):
     from repro.configs.base import ArchConfig
-    return ArchConfig(name=name, family="dense", **fields)
+    return ArchConfig(name=name, family=family, **fields)
 
 
 def build_cluster(arch, params, serving: Dict):
@@ -132,11 +132,6 @@ def page_bytes(cluster) -> int:
     pool = cluster.prefix_engine._kv_pool
     return (pool.shape[1] * pool.dtype.itemsize
             * cluster.prefix_block_tokens)
-
-
-def token_row_elems(arch) -> int:
-    """Elements of KV one token holds in the pool: K and V of every layer."""
-    return 2 * arch.n_layers * arch.n_kv_heads * arch.head_dim()
 
 
 def warm_lease_shapes(cluster, max_groups: int, max_blocks: int) -> None:

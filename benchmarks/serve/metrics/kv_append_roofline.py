@@ -17,5 +17,6 @@ def read(run):
         return None
     rows = sum(len(t["decoded"]) + sum(p - s for p, s in t["admitted"])
                for t in run.trace["ticks"])
-    least = flops.append_bytes(run.sizes, rows) / run.peaks["hbm_bytes_per_s"]
+    least = flops.append_bytes(run.model.kv_row_bytes(run.sizes), rows) \
+        / run.peaks["hbm_bytes_per_s"]
     return 100.0 * least / (ns * 1e-9)

@@ -1,6 +1,7 @@
 """Paged flash-decode attention: the least time its needed work takes on
-the chip (valid tokens' K/V of the layer, query and output; useful FLOPs
-only), over the kernel's device time in the trace, in %."""
+the chip (the model's ``paged_attn_work``: valid tokens' K/V of each layer
+that runs the kernel, query and output; useful FLOPs only), over the
+kernel's device time in the trace, in %."""
 import flops
 import tracing
 
@@ -17,7 +18,8 @@ def read(run):
     for t in run.trace["ticks"]:
         if not t["decoded"]:
             continue
-        work = [flops.paged_attn_work(run.sizes, p) for p in t["decoded"]]
-        least += run.sizes.n_layers * flops.roofline_seconds(
+        work = [run.model.paged_attn_work(run.sizes, p)
+                for p in t["decoded"]]
+        least += flops.roofline_seconds(
             sum(f for f, _ in work), sum(b for _, b in work), run.peaks)
     return 100.0 * least / (ns * 1e-9)

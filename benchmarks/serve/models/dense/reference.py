@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import weights
+from . import weights
 
 HIGHEST = jax.lax.Precision.HIGHEST
 BLOCK_Q = 512              # query rows per attention block

@@ -36,9 +36,7 @@ def main(argv=None) -> int:
     cell = bench.load_cell(args.workload)
     bench.add_libtpu_flags(cell)
     driver.import_program()
-    import dataclasses
     import jax
-    import weights
     if jax.devices()[0].platform != "tpu":
         bench.log("sweep: JAX found no TPU")
         return 3
@@ -47,8 +45,9 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     s = cell.sizes
     cluster = driver.build_cluster(
-        driver.arch_config(cell.config_name, dataclasses.asdict(s)),
-        weights.make_params(args.seed, s), cell.serving)
+        driver.arch_config(cell.config_name, cell.model.FAMILY,
+                           cell.model.arch_fields(s)),
+        cell.model.make_params(args.seed, s), cell.serving)
     bench.warm(cluster, cell, comp)
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):

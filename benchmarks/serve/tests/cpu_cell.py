@@ -10,7 +10,6 @@ interpret mode.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import os
 import sys
 
@@ -24,11 +23,7 @@ import driver  # noqa: E402
 
 def reduced_cell(workload: str):
     cell = bench.load_cell(workload)
-    s = cell.sizes
-    cell.sizes = dataclasses.replace(
-        s, n_layers=2, d_model=64, n_heads=4,
-        n_kv_heads=2 if s.n_kv_heads > 2 else 1, d_head=16, d_ff=128,
-        vocab=512)
+    cell.sizes = cell.model.cpu_sizes(cell.sizes)
     return cell
 
 

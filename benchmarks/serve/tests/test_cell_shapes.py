@@ -4,14 +4,16 @@ nothing once set-up is over: every decode, prefill, suffix-prefill, append
 and lease shape the window reaches was in the warm set.
 
 The share of prompt tokens read from leased pages is an exact count and
-does not depend on the device; it is printed for the shared cell.
+does not depend on the device; it is printed for the shared cell, and is
+0 where every request's head is its own.
 """
 import pytest
 
 import bench
 import cpu_cell
 
-CELLS = ["mistral-nemo-12b.agent_shared", "glm4-9b.reasoning_overload"]
+CELLS = ["mistral-nemo-12b.agent_shared", "glm4-9b.reasoning_overload",
+         "mistral-nemo-12b.unshared_control"]
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -28,3 +30,6 @@ def test_window_compiles_nothing(workload):
               f"({win['prefill_tokens_skipped']} of "
               f"{win['prompt_tokens_admitted']} prompt tokens)")
         assert share > 0
+    if cell.mix.get("unique_prefix"):
+        assert win["prompt_tokens_admitted"] > 0, win
+        assert win["prefill_tokens_skipped"] == 0, win

@@ -1,5 +1,5 @@
 """Model FLOPs and needed bytes against hand counts for both cells'
-configurations."""
+configurations, reached through the configuration's model."""
 import pytest
 
 import bench
@@ -8,8 +8,8 @@ import flops
 
 @pytest.fixture(params=["mistral-nemo-12b.agent_shared",
                         "glm4-9b.reasoning_overload"])
-def sizes(request):
-    return bench.load_cell(request.param).sizes
+def cell(request):
+    return bench.load_cell(request.param)
 
 
 HAND = {
@@ -23,36 +23,45 @@ HAND = {
 }
 
 
-def test_layer_params_match_the_published_widths(sizes):
+def test_layer_params_match_the_published_widths(cell):
+    sizes, work = cell.sizes, cell.model.work
     hand = HAND[sizes.d_model]
-    assert flops.layer_matmul_params(sizes) == hand["layer"]
-    assert flops.matmul_params(sizes) == hand["layers"] * hand["layer"]
-    assert flops.head_flops(sizes) == 2 * hand["head"]
-    assert flops.kv_row_bytes(sizes) == hand["row"]
+    assert work.layer_matmul_params(sizes) == hand["layer"]
+    assert work.matmul_params(sizes) == hand["layers"] * hand["layer"]
+    assert work.head_flops(sizes) == 2 * hand["head"]
+    assert cell.model.kv_row_bytes(sizes) == hand["row"]
+    assert cell.model.token_row_elems(sizes) == hand["row"] // 2
 
 
 def test_literal_counts():
-    m = bench.load_cell("mistral-nemo-12b.agent_shared").sizes
-    g = bench.load_cell("glm4-9b.reasoning_overload").sizes
-    assert flops.layer_matmul_params(m) == 272_629_760
-    assert flops.layer_matmul_params(g) == 203_948_032
-    assert flops.kv_row_bytes(m) == 40_960
-    assert flops.kv_row_bytes(g) == 20_480
-    assert flops.decode_token_flops(m, 999) == 6_958_612_480
-    assert flops.paged_attn_work(m, 1000) == (16_400_384, 4_116_480)
-    assert flops.paged_attn_work(g, 1000) == (
-        4 * 32 * 128 * 1001, 1001 * 2 * 2 * 128 * 2 + 2 * 32 * 128 * 2)
-    assert flops.append_bytes(m, 16) == 1_310_720
+    mc = bench.load_cell("mistral-nemo-12b.agent_shared")
+    gc = bench.load_cell("glm4-9b.reasoning_overload")
+    model, m, g = mc.model, mc.sizes, gc.sizes
+    assert gc.model is model
+    assert model.work.layer_matmul_params(m) == 272_629_760
+    assert model.work.layer_matmul_params(g) == 203_948_032
+    assert model.kv_row_bytes(m) == 40_960
+    assert model.kv_row_bytes(g) == 20_480
+    assert model.decode_token_flops(m, 999) == 6_958_612_480
+    # per layer, times the layers that run the paged kernel (all of them)
+    assert model.paged_attn_work(m, 1000) == (10 * 16_400_384,
+                                              10 * 4_116_480)
+    assert model.paged_attn_work(g, 1000) == (
+        20 * 4 * 32 * 128 * 1001,
+        20 * (1001 * 2 * 2 * 128 * 2 + 2 * 32 * 128 * 2))
+    assert flops.append_bytes(model.kv_row_bytes(m), 16) == 1_310_720
 
 
-def test_prefill_sums_each_position(sizes):
+def test_prefill_sums_each_position(cell):
+    sizes, model = cell.sizes, cell.model
     skip, prompt = 4096, 4196
-    by_hand = sum(2 * flops.matmul_params(sizes)
-                  + flops.attention_flops(sizes, p + 1)
-                  for p in range(skip, prompt)) + flops.head_flops(sizes)
-    assert flops.prefill_flops(sizes, skip, prompt) == by_hand
+    by_hand = sum(2 * model.work.matmul_params(sizes)
+                  + model.work.attention_flops(sizes, p + 1)
+                  for p in range(skip, prompt)) \
+        + model.work.head_flops(sizes)
+    assert model.prefill_flops(sizes, skip, prompt) == by_hand
     # a full prefill of one token is one decode token with nothing before
-    assert flops.prefill_flops(sizes, 0, 1) == flops.decode_token_flops(
+    assert model.prefill_flops(sizes, 0, 1) == model.decode_token_flops(
         sizes, 0)
 
 

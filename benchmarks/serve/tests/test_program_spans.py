@@ -22,7 +22,7 @@ NEW = (queue_wait_ms, admit_ms, admit_host_ms, lease_wave_ms,
 
 def _run(open_, close):
     return bench.Run(ticks=[], counters_open=open_, counters_close=close,
-                     sizes=None, peaks={}, trace=None)
+                     model=None, sizes=None, peaks={}, trace=None)
 
 
 OPEN = {"requests_admitted": 3, "queue_wait_ns": 5_000_000,

@@ -8,14 +8,15 @@ import pytest
 
 import bench
 import driver
-import reference
-import weights
+
+CELL = bench.load_cell("mistral-nemo-12b.agent_shared")
+DENSE = CELL.model               # the configuration's model package
+weights, reference = DENSE.weights, DENSE.reference
 
 
 @pytest.fixture(scope="module")
 def small():
-    cell = bench.load_cell("mistral-nemo-12b.agent_shared")
-    return dataclasses.replace(cell.sizes, n_layers=2, d_model=64, n_heads=4,
+    return dataclasses.replace(CELL.sizes, n_layers=2, d_model=64, n_heads=4,
                                n_kv_heads=2, d_head=16, d_ff=96, vocab=300)
 
 
@@ -41,7 +42,7 @@ def test_reference_matches_the_program_forward_in_float32(small):
     driver.import_program()
     from repro.models.transformer import forward, logits_fn
     seed = 11
-    arch = driver.arch_config("small", dataclasses.asdict(small))
+    arch = driver.arch_config("small", DENSE.FAMILY, DENSE.arch_fields(small))
     params = jax.tree.map(lambda t: t.astype(jnp.float32),
                           weights.make_params(seed, small))
     toks = np.random.default_rng(0).integers(1, small.vocab, 300)

@@ -85,9 +85,9 @@ def test_recorded_chip_trace():
     # one pool scatter per decode step, one more per admission
     _, n = tracing.kernel_ns(rec, kv_append_roofline.PATTERN)
     assert n == decode_ticks + sum(len(t["admitted"]) for t in ticks)
+    cell = bench.load_cell(WORKLOAD)
     run = SimpleNamespace(trace={"record": rec, "ticks": ticks},
-                          sizes=bench.load_cell(WORKLOAD).sizes,
-                          peaks=PEAKS)
+                          model=cell.model, sizes=cell.sizes, peaks=PEAKS)
     for mod in (paged_attn_roofline, kv_append_roofline):
         assert 0 < mod.read(run) <= 100
     assert 0 <= device_idle_share.read(run) < 100

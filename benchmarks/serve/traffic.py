@@ -11,6 +11,9 @@ A mix file (``traffic/<mix>.json``) holds parameters only:
 - ``shared_prefix``: ``tenants`` system prompts of ``tokens`` tokens each,
   drawn from the file's own ``seed`` (fixed, so they are the same prompts in
   every run), or null;
+- ``unique_prefix``: every request's prompt starts with a head of its own of
+  ``tokens`` tokens, drawn from the run's seed, so no request shares a page
+  with another (absent: nothing is drawn for it);
 - ``prompt_tokens``: the length of each request's own part of the prompt,
   a log-normal (``median``, ``sigma``) clipped to [``min``, ``max``] and
   discretized to ``support`` points; ``output_tokens``: the same without
@@ -45,7 +48,7 @@ ORDER_STREAM = 1
 class Req:
     idx: int
     due: float                # seconds after the window opens
-    prompt: np.ndarray        # int32: shared prefix (if any) + own part
+    prompt: np.ndarray        # int32: own head, shared prefix, own part
     max_new: int
     tenant: int               # index of the shared prefix, -1 for none
 
@@ -106,6 +109,11 @@ def shared_prompts(mix: dict, vocab: int) -> List[np.ndarray]:
             for _ in range(sp["tenants"])]
 
 
+def unique_head_tokens(mix: dict) -> int:
+    """Tokens of the head each request has of its own (0: none)."""
+    return int((mix.get("unique_prefix") or {}).get("tokens", 0))
+
+
 def generate(mix: dict, seed: int, seconds: float, vocab: int,
              rate: Optional[float] = None) -> List[Req]:
     """The window's requests, ordered by due time.  ``rate`` overrides the
@@ -126,11 +134,15 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int,
     prefixes = shared_prompts(mix, vocab)
     tenants = (order.permutation(np.arange(n) % len(prefixes))
                if prefixes else np.full(n, -1))
+    head = unique_head_tokens(mix)
     rng = np.random.default_rng(seed)
     reqs = []
     for i in range(n):
         tok = rng.integers(1, vocab, int(own[i])).astype(np.int32)
         t = int(tenants[i])
         prompt = np.concatenate([prefixes[t], tok]) if t >= 0 else tok
+        if head:
+            prompt = np.concatenate(
+                [rng.integers(1, vocab, head).astype(np.int32), prompt])
         reqs.append(Req(i, float(due[i]), prompt, int(out[i]), t))
     return reqs
