@@ -564,7 +564,7 @@ class LeaseEngine:
         if self.backend == "pallas":
             self._kv_pool = rows
         else:
-            self._kv_pool = np.asarray(rows)
+            self._kv_pool = np.array(rows)        # writable: appends land here
         self.stats.kv_tokens_appended += int(tokens_appended)
         for name in self.kv_pools:
             self.stats.kv_pool_tokens[name] = (
@@ -590,20 +590,25 @@ class LeaseEngine:
         its column window only -- neighbors' segments keep their bits, and
         validity is left untouched (publishing a block's content for every
         stack is ``write_kv``'s job).
+
+        A negative id writes nothing (a prefill bucket's pad rows past its
+        prompt).  On the ``pallas`` backend device rows are scattered as
+        they come, without a host copy; the numpy mirror converts them.
         """
         rows_idx = np.atleast_1d(np.asarray(rows_idx, np.int64))
-        if not rows_idx.size:
+        keep = rows_idx >= 0
+        n_kept = int(keep.sum())
+        if not n_kept:
             return
         if pool is not None:
             meta = self._pool_meta[pool]
-            rows = np.asarray(token_rows).reshape(rows_idx.size,
-                                                  meta["token_elems"])
             if self.backend == "pallas":
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore", message=".*donat.*")
                     self._kv_pool = lease_ops.append_rows(
-                        self._kv_pool,
-                        jnp.asarray(rows_idx, jnp.int32), jnp.asarray(rows),
+                        self._kv_pool, jnp.asarray(rows_idx, jnp.int32),
+                        jnp.asarray(token_rows).reshape(
+                            rows_idx.size, meta["token_elems"]),
                         col_lo=meta["offset"], width=meta["token_row"],
                         interpret=self.interpret)
             else:
@@ -611,21 +616,24 @@ class LeaseEngine:
                 # padding), exactly like the kernel's LANES-block DMA --
                 # touching only token_elems columns would leave the padding
                 # bits behind and break kernel/mirror bit-identity
-                flat = np.zeros((rows_idx.size, meta["token_row"]),
+                rows = np.asarray(token_rows).reshape(
+                    rows_idx.size, meta["token_elems"])[keep]
+                flat = np.zeros((n_kept, meta["token_row"]),
                                 self._kv_pool.dtype)
                 flat[:, :meta["token_elems"]] = rows.astype(
                     self._kv_pool.dtype)
                 self._kv_pool[
-                    rows_idx,
+                    rows_idx[keep],
                     meta["offset"]:meta["offset"] + meta["token_row"]] = flat
-            self.stats.kv_tokens_appended += int(rows_idx.size)
+            self.stats.kv_tokens_appended += n_kept
             self.stats.kv_pool_tokens[pool] = (
-                self.stats.kv_pool_tokens.get(pool, 0) + int(rows_idx.size))
+                self.stats.kv_pool_tokens.get(pool, 0) + n_kept)
             if self._san is not None:       # validity untouched on this path
                 self._san.after(self, "append_kv",
                                 blocks=np.zeros(0, np.int64))
             return
-        rows = np.asarray(token_rows).reshape(rows_idx.size, -1)
+        xp = jnp if self.backend == "pallas" else np
+        rows = xp.asarray(token_rows).reshape(rows_idx.size, -1)
         if rows.shape[1] != self.kv_token_row:
             # legacy single-pool form: unpadded token_elems rows
             meta = self._pool_meta[self._single_pool()]
@@ -634,19 +642,18 @@ class LeaseEngine:
             with warnings.catch_warnings():
                 warnings.filterwarnings("ignore", message=".*donat.*")
                 self._kv_pool = lease_ops.append_rows(
-                    self._kv_pool, jnp.asarray(rows_idx, jnp.int32),
-                    jnp.asarray(rows), interpret=self.interpret)
+                    self._kv_pool, jnp.asarray(rows_idx, jnp.int32), rows,
+                    interpret=self.interpret)
         else:
-            flat = np.zeros((rows_idx.size, self.kv_token_row),
-                            self._kv_pool.dtype)
-            flat[:, :rows.shape[1]] = rows
-            self._kv_pool[rows_idx] = flat
-        blocks = np.unique(rows_idx // self.kv_chunk)
+            flat = np.zeros((n_kept, self.kv_token_row), self._kv_pool.dtype)
+            flat[:, :rows.shape[1]] = rows[keep]
+            self._kv_pool[rows_idx[keep]] = flat
+        blocks = np.unique(rows_idx[keep] // self.kv_chunk)
         self._kv_valid[blocks] = True
-        self.stats.kv_tokens_appended += int(rows_idx.size)
+        self.stats.kv_tokens_appended += n_kept
         for name in self.kv_pools:       # a full row feeds every stack
             self.stats.kv_pool_tokens[name] = (
-                self.stats.kv_pool_tokens.get(name, 0) + int(rows_idx.size))
+                self.stats.kv_pool_tokens.get(name, 0) + n_kept)
         if self._san is not None:
             self._san.after(self, "append_kv", blocks=blocks)
 

@@ -339,7 +339,7 @@ def _scatter_kernel(idx_ref, rows_ref, pool_hbm, out_hbm, buf, sem, *,
     i = pl.program_id(0)
     row = idx_ref[i]
 
-    @pl.when(row < n_rows)               # an id past the pool: no write
+    @pl.when((row >= 0) & (row < n_rows))   # an id off the pool: no write
     def _write():
         window = _window(out_hbm, row, tile, col_lo, width)
         load = pltpu.make_async_copy(window, buf, sem)
@@ -369,7 +369,8 @@ def scatter_rows(pool, idx, rows, *, col_lo: int = 0,
     stack's segment of the interleaved token row.  Each step waits for its
     write before the next step reads, so rows listed twice keep the last
     write, and rows that share a tile all land.  An id at or past the
-    pool's end is skipped, as XLA's scatter drops it.
+    pool's end is skipped, as XLA's scatter drops it, and so is a negative
+    id (a prefill bucket's pad rows past its prompt).
     """
     n, width = rows.shape
     assert col_lo % LANES == 0 and width % LANES == 0, (col_lo, width)

@@ -157,7 +157,8 @@ def append_rows(pool, idx, rows, col_lo: int = 0, width: int = None,
     pool (N, W); idx (n,) int32; rows (n, w) right-padded with zeros to
     ``width`` (default: the pool's full row width).  ``col_lo`` places the
     window at a stack's segment of an interleaved multi-pool token row --
-    columns outside [col_lo, col_lo + width) keep their bits.  Returns the
+    columns outside [col_lo, col_lo + width) keep their bits, and an id off
+    the pool (negative, or at or past its end) writes nothing.  Returns the
     updated pool; the input pool buffer is donated/aliased so no full-pool
     copy happens on TPU.
     """

@@ -70,14 +70,17 @@ def append_rows_ref(pool, idx, rows, col_lo: int = 0, width: int = None):
     """Oracle for the append-KV scatter: pool.at[idx, window].set(rows) with
     rows right-padded to the window width (last write wins on duplicates);
     ``col_lo``/``width`` select a stack's column window of an interleaved
-    multi-pool token row (default: the whole row)."""
+    multi-pool token row (default: the whole row).  An id off the pool,
+    negative or at or past its end, writes nothing."""
     if width is None:
         width = pool.shape[1] - col_lo
     w = rows.shape[1]
     if w != width:
         rows = jnp.pad(rows, ((0, 0), (0, width - w)))
-    return pool.at[jnp.asarray(idx), col_lo:col_lo + width].set(
-        rows.astype(pool.dtype))
+    idx = jnp.asarray(idx)
+    idx = jnp.where(idx < 0, pool.shape[0], idx)
+    return pool.at[idx, col_lo:col_lo + width].set(
+        rows.astype(pool.dtype), mode="drop")
 
 
 def gather_blocks_ref(pool, idx, col_lo: int = 0, width: int = None):

@@ -18,13 +18,14 @@ this path.  The pool is split into a content-addressed region
 (prompt-prefix chunks chain-hashed to block ids, shared across requests
 under leases) and an allocator region (private decode pages, free-listed).
 A request's page table names its covered shared-prefix blocks followed by
-its own pages; prefill scatters the prompt's suffix KV into the own pages
+its own pages; prefill scatters the prompt's suffix KV, packed into pool
+token rows inside the prefill program, into the own pages
 (``LeaseEngine.append_kv``) and each decode step appends the new token's
 KV through the ``tardis_lease`` scatter kernel inside the jitted step
-(:func:`repro.models.decode_step_paged`) -- no host round trip.  Decode
-attention streams K/V straight out of the pool (the gather path is
-bit-exact with the dense-cache decode; the Pallas paged flash-decode
-kernel is routed on TPU).  The moe family's DUAL cache stacks (leading
+(:func:`repro.models.decode_step_paged`) -- neither takes a host round
+trip.  Decode attention streams K/V straight out of the pool (the gather
+path is bit-exact with the dense-cache decode; the Pallas paged
+flash-decode kernel is routed on TPU).  The moe family's DUAL cache stacks (leading
 dense layers + moe layers) page through one engine with **named KV
 pools**: each stack's segment interleaves into the same token row at a
 static pool offset (:func:`repro.models.pool_layout` is the layout's
@@ -84,12 +85,12 @@ KV_POOL_FAMILIES = PAGED_FAMILIES
 
 # the serving loop's host spans (``span_<name>_ns`` / ``span_<name>_n``):
 # a whole admission group and, inside it, the lease wave, the leased
-# prefix's materialisation, the wait on the prefill, the suffix KV's trip
-# through the host and its append; a decode step and, inside it, the
-# decode-lease renewals and the wait on the step; the per-tick rebase check
-SPAN_NAMES = ("admit", "lease_wave", "pool_read", "prefill_wait",
-              "kv_to_host", "kv_append", "decode", "renew", "decode_wait",
-              "rebase")
+# prefix's materialisation, the suffix KV's append into its pages and the
+# wait on the prefill (the read of its last logits); a decode step and,
+# inside it, the decode-lease renewals and the wait on the step; the
+# per-tick rebase check
+SPAN_NAMES = ("admit", "lease_wave", "pool_read", "kv_append",
+              "prefill_wait", "decode", "renew", "decode_wait", "rebase")
 
 
 @dataclasses.dataclass
@@ -232,6 +233,23 @@ def _prefix_cache(stacks, pkv, batch, cache_len: int, skip: int):
     return cache
 
 
+def _token_rows(stacks, cache, lo: int, hi: int, row_w: int, dtype):
+    """Positions [lo, hi) of a B=1 prefill cache as (hi-lo, row_w) FULL
+    pool token rows in the pool's dtype: every stack's layers' K then V at
+    its pool offset (``pool_layout``), zeros in the lane padding between
+    and after the segments -- one append covers every cache stack."""
+    m = hi - lo
+    parts, at = [], 0
+    for s in stacks:
+        kv = [cache[key][:, 0, lo:hi].transpose(1, 0, 2, 3).reshape(m, -1)
+              for key in s.cache_keys]
+        parts += [jnp.zeros((m, s.offset - at), dtype)] + \
+            [x.astype(dtype) for x in kv]
+        at = s.offset + s.token_elems
+    parts.append(jnp.zeros((m, row_w - at), dtype))
+    return jnp.concatenate(parts, axis=1)
+
+
 class ServingCluster:
     """N replicas + weight publisher + shared paged-KV LeaseEngine pool."""
 
@@ -347,6 +365,10 @@ class ServingCluster:
             # admission: requests admitted (prefill-pod shadows excluded)
             # and the time they waited in the queue before it
             "requests_admitted": 0, "queue_wait_ns": 0,
+            # pool rows admissions wrote from the prefill's own output on
+            # the device (positions [skip, plen)), and the bucket's pad rows
+            # past the prompt that the scatter skipped
+            "admit_rows_on_device": 0, "admit_rows_skipped": 0,
         }
         self._span = Spans(self.prefix_stats, SPAN_NAMES)
         # disaggregated serving role: "mixed" (default, serves everything),
@@ -374,14 +396,24 @@ class ServingCluster:
                 return decode_step_paged(cfg, p, pool, pr, ln, tk, chunk=ch,
                                          interpret=interp)
 
+            # each prefill also returns positions [skip, cache_len) of its
+            # cache as pool token rows, so admission appends them on the
+            # device
+            row_w = self.prefix_engine.kv_token_row
+            row_dtype = self.prefix_engine._kv_pool.dtype
+
             def prefill(p, b, cl, li):
-                return models.prefill(cfg, p, b, cl, last_idx=li)
+                cache, logits = models.prefill(cfg, p, b, cl, last_idx=li)
+                return cache, logits, _token_rows(stacks, cache, 0, cl,
+                                                  row_w, row_dtype)
 
             def prefill_suffix(p, b, pkv, n, cl, li):
-                return models.prefill_suffix(
+                cache, logits = models.prefill_suffix(
                     cfg, p, b,
                     _prefix_cache(stacks, pkv, b["tokens"].shape[0], cl, n),
                     n, last_idx=li)
+                return cache, logits, _token_rows(stacks, cache, n, cl,
+                                                  row_w, row_dtype)
 
             self._decode_paged_fn = jax.jit(decode_paged,
                                             donate_argnums=(1,))
@@ -771,23 +803,6 @@ class ServingCluster:
                 bt, 2, s.n_layers * hk, dh)      # (bt, 2, L_s*hk, dh)
         return out
 
-    def _cache_token_rows(self, cache, lo: int, hi: int) -> np.ndarray:
-        """Positions [lo, hi) of a B=1 prefill cache as (hi-lo,
-        kv_token_row) FULL pool token rows: every stack's segment packed at
-        its pool offset (the stack's layers' K then V), zeros in the
-        inter-segment lane padding -- one append covers both cache
-        stacks."""
-        m = hi - lo
-        rows = np.zeros((m, self.prefix_engine.kv_token_row), np.float32)
-        for s in self._stacks:
-            k = np.asarray(cache[s.cache_keys[0]][:, 0, lo:hi])
-            v = np.asarray(cache[s.cache_keys[1]][:, 0, lo:hi])
-            kr = k.transpose(1, 0, 2, 3).reshape(m, -1)
-            vr = v.transpose(1, 0, 2, 3).reshape(m, -1)
-            rows[:, s.offset:s.offset + s.token_elems] = \
-                np.concatenate([kr, vr], axis=1)
-        return rows
-
     # -- continuous-batching paged scheduler --------------------------------
 
     def _pages_needed(self, req: Request, covered: int = 0) -> int:
@@ -905,14 +920,14 @@ class ServingCluster:
                 with self._span("pool_read"):
                     mat_cache[key] = self._pool_to_stack_kv(
                         self._read_kv_stacks(list(key)))
-            cache, logits = self._psuffix_fn(params, {"tokens": toks},
-                                             mat_cache[key], skip,
-                                             cache_len, last)
+            cache, logits, rows = self._psuffix_fn(
+                params, {"tokens": toks}, mat_cache[key], skip, cache_len,
+                last)
             ps["prefix_prefill_tokens_skipped"] += skip
             ps["prefix_flops_saved"] += skip * self._flops_per_token
         else:
-            cache, logits = self._prefill_fn(params, {"tokens": toks},
-                                             cache_len, last)
+            cache, logits, rows = self._prefill_fn(
+                params, {"tokens": toks}, cache_len, last)
         # payload write-back: the blocks this request owns per the plan
         # (every cache stack's payload published in one write_kv)
         wb = [(bid, c) for bid, (ri, c) in plan.miss_writers.items()
@@ -951,18 +966,22 @@ class ServingCluster:
             self._pins[bid] = self._pins.get(bid, 0) + 1
         if own:
             self._pool_wver[own] = -1 if wver is None else int(wver)
-        # the prompt's suffix KV lands in the own pages, token-granular
-        # (the host reads the cache here: wait for the prefill first, so
-        # the wait and the copy are told apart)
-        with self._span("prefill_wait"):
-            jax.block_until_ready((cache, logits))
-        with self._span("kv_to_host"):
-            rows = self._cache_token_rows(cache, skip, plen)
-        pos = np.arange(skip, plen)
-        flat_idx = (page_row[pos // bt].astype(np.int64) * bt + pos % bt)
+        # the prompt's suffix KV lands in the own pages, token-granular,
+        # without leaving the device: the prefill's rows cover its whole
+        # bucket, and positions past the prompt get id -1, which the
+        # scatter skips
+        pos = np.arange(skip, cache_len)
+        flat_idx = np.where(
+            pos < plen, page_row[pos // bt].astype(np.int64) * bt + pos % bt,
+            -1)
         with self._span("kv_append"):
             eng.append_kv(flat_idx, rows)
-        t0 = int(np.argmax(np.asarray(logits[0, -1])))
+        ps["admit_rows_on_device"] += plen - skip
+        ps["admit_rows_skipped"] += cache_len - plen
+        # the admission's one wait on the device: the prefill's last logits
+        with self._span("prefill_wait"):
+            last_logits = np.asarray(logits[0, -1])
+        t0 = int(np.argmax(last_logits))
         stream = Stream(req=req, page_row=page_row, own_pages=own,
                         shared_bids=list(bids[:covered]), reloc_pages=[],
                         length=plen, emitted=[t0], reserved_pages=total_pages)

@@ -248,6 +248,42 @@ def test_scatter_rows_skips_ids_past_pool(col_lo, w):
     np.testing.assert_array_equal(np.asarray(out), ref)
 
 
+@pytest.mark.parametrize("col_lo,w", [(0, 256), (128, 128)])
+def test_scatter_rows_skips_negative_ids(col_lo, w):
+    """A negative id (a prefill bucket's pad row past its prompt) writes
+    nothing, in the kernel, its oracle and the engine's numpy mirror; the
+    other ids write what they wrote with the negative ones left out."""
+    from repro.core.lease_engine import LeaseEngine
+    from repro.kernels.tardis_lease.kernel import scatter_rows
+    rng = np.random.default_rng(w + 1)
+    pool = rng.standard_normal((64, 256)).astype(np.float32)
+    idx = np.array([5, -1, 0, -64, 63, 5, -3], np.int32)
+    rows = rng.standard_normal((idx.size, w)).astype(np.float32)
+    keep = idx >= 0
+    want = pool.copy()
+    for i, r in zip(idx[keep], rows[keep]):      # last write wins
+        want[i, col_lo:col_lo + w] = r
+    outs = [
+        scatter_rows(jnp.asarray(pool), jnp.asarray(idx), jnp.asarray(rows),
+                     col_lo=col_lo, interpret=True),
+        scatter_rows(jnp.asarray(pool), jnp.asarray(idx[keep]),
+                     jnp.asarray(rows[keep]), col_lo=col_lo, interpret=True),
+        append_rows_ref(jnp.asarray(pool), idx, rows, col_lo, w)]
+    if col_lo == 0:
+        # the engine's full-row append: 16 blocks of 4 token rows of 256
+        for backend in ("pallas", "numpy"):
+            eng = LeaseEngine(16, lease=8, backend=backend,
+                              kv_pools={"kv": (4, 2, 8, 16)},
+                              kv_dtype=np.float32)
+            eng.set_kv_rows(jnp.asarray(pool))
+            eng.append_kv(idx, rows)
+            assert eng.stats.kv_tokens_appended == int(keep.sum())
+            assert [b for b in range(16) if eng.kv_ok(b)] == [0, 1, 15]
+            outs.append(eng.kv_rows_view())
+    for out in outs:
+        np.testing.assert_array_equal(np.asarray(out), want)
+
+
 @pytest.mark.parametrize("n", [4352, 4608])     # 34 and 36 rows of 128
 @pytest.mark.parametrize("lease", ["static", "predicted"])
 def test_tardis_table_kernels_unaligned_rows(n, lease):

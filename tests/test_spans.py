@@ -24,8 +24,7 @@ from repro.runtime.spans import Spans
 KW = dict(n_replicas=1, prefix_block_tokens=4,
           policy=CoherencePolicy(lease=16), cache_len=96, selfinc_period=4,
           n_decode_pages=64, max_pages=16, max_batch=2)
-ADMIT_CHILDREN = ("lease_wave", "pool_read", "prefill_wait", "kv_to_host",
-                  "kv_append")
+ADMIT_CHILDREN = ("lease_wave", "pool_read", "kv_append", "prefill_wait")
 DECODE_CHILDREN = ("renew", "decode_wait")
 
 
@@ -85,8 +84,11 @@ def test_span_counts_match_the_scheduler(cfg, params):
     assert rep["span_renew_n"] == rep["span_decode_wait_n"] == decode_ticks
     assert 1 < groups < len(reqs)
     assert rep["span_admit_n"] == rep["span_lease_wave_n"] == groups
-    # every admission waits on its prefill and moves its suffix KV once
-    for name in ("prefill_wait", "kv_to_host", "kv_append"):
+    # every admission appends its suffix KV and waits on its prefill once;
+    # the KV takes no trip through the host, so no span is kept for one
+    assert set(SPAN_NAMES) == {"admit", *ADMIT_CHILDREN, "decode",
+                               *DECODE_CHILDREN, "rebase"}
+    for name in ("kv_append", "prefill_wait"):
         assert rep[f"span_{name}_n"] == len(reqs)
     assert 0 < rep["span_pool_read_n"] <= len(reqs)
     assert rep["requests_admitted"] == len(reqs)
@@ -101,6 +103,45 @@ def test_nested_spans_fit_inside_their_parent(cfg, params):
         <= rep["span_admit_ns"]
     assert sum(rep[f"span_{n}_ns"] for n in DECODE_CHILDREN) \
         <= rep["span_decode_ns"]
+
+
+def test_admission_waits_on_the_device_once_after_its_append(
+        cfg, params, monkeypatch):
+    """Inside an admission the KV append reads no device array back to the
+    host, and the one wait on the prefill (the read of its last logits)
+    comes after the append has been dispatched."""
+    cluster = ServingCluster(cfg, lambda: params, **KW)
+    spans, order, reads = cluster._span, [], []
+    array_t = type(jnp.zeros(()))
+    to_host = array_t.__array__
+
+    def counted(self, *a, **k):
+        if order and order[-1] == "kv_append":
+            reads.append(self.shape)
+        return to_host(self, *a, **k)
+
+    monkeypatch.setattr(array_t, "__array__", counted)
+
+    class Recorded:
+        def __init__(self, name):
+            self._span = spans(name)
+            self._name = name
+
+        def __enter__(self):
+            order.append(self._name)
+            return self._span.__enter__()
+
+        def __exit__(self, *exc):
+            order.append(f"/{self._name}")
+            return self._span.__exit__(*exc)
+
+    cluster._span = Recorded
+    reqs = _requests(cfg, 4, seed=2)
+    cluster.run(reqs)
+    assert all(r.done for r in reqs)
+    assert reads == []
+    inner = [n for n in order if n in ("kv_append", "prefill_wait")]
+    assert inner == ["kv_append", "prefill_wait"] * len(reqs)
 
 
 @pytest.mark.parametrize("roles", [["mixed", "mixed"], ["prefill", "decode"]])
